@@ -20,9 +20,9 @@ direct reads (``os.environ.get("KSS_X")``, ``os.getenv``, subscripts,
 (``ENV_VAR = "KSS_TRACE"`` then ``os.environ.get(ENV_VAR)``), and
 module-local reader helpers whose *parameter* is the variable name
 (``_env_number(name, ...)`` in utils/broker.py, ``_env_int(env, name,
-...)`` in server/sessions.py). Underscore-prefixed internal sentinels
-(``_KSS_SERVER_CPU_FALLBACK``) are process-internal plumbing, not
-operator configuration, and are out of scope.
+...)`` in server/sessions.py). Underscore-prefixed names (``_KSS_*``)
+would be process-internal plumbing, not operator configuration, and
+are out of scope.
 """
 
 from __future__ import annotations

@@ -73,10 +73,15 @@ FINGERPRINT_BASENAME = "kss-fingerprints.json"
 
 ENV_VAR = "KSS_JAXPR_AUDIT"
 
-# host-callback primitive names (KSS711) and the user-facing APIs that
-# create them (KSS701). jax.debug.print lowers to debug_callback.
+# host-effect primitives (KSS711) and the user-facing APIs that create
+# them (KSS701). An eqn is a host effect when it carries one of the host
+# effect types (matched by type NAME, the stable part across jax
+# versions: jax 0.9 traces jax.debug.print to `debug_print` with a
+# DebugEffect) or is a callback primitive by name (`pure_callback`
+# carries no effect at all).
 CALLBACK_PRIMS = ("pure_callback", "io_callback", "debug_callback")
 CALLBACK_APIS = ("pure_callback", "io_callback", "debug_callback")
+HOST_EFFECTS = ("DebugEffect", "IOEffect", "OrderedIOEffect")
 
 # dims <= this are structural (plugin counts, taint slots, tuple
 # widths) and never bucket-checked; larger dims must be powers of two
@@ -356,21 +361,20 @@ class JaxprAuditor:
         in_avals = tuple(_aval_sig(v.aval) for v in closed.jaxpr.invars)
         out_avals = tuple(_aval_sig(v.aval) for v in closed.jaxpr.outvars)
 
-        # KSS711 — host-callback primitives, any depth
+        # KSS711 — host-effect primitives, any depth
         for prim, depth in _walk_prims(closed.jaxpr):
-            if prim in CALLBACK_PRIMS or prim.endswith("_callback"):
-                findings.append(
-                    Finding(
-                        "KSS711",
-                        site,
-                        0,
-                        f"host-callback primitive {prim!r} in the traced "
-                        f"program (depth {depth}) — a device→host sync "
-                        f"per execution",
-                        hint="hoist the host work out of the traced "
-                        "body (see KSS701)",
-                    )
+            findings.append(
+                Finding(
+                    "KSS711",
+                    site,
+                    0,
+                    f"host-effect primitive {prim!r} in the traced "
+                    f"program (depth {depth}) — a device→host sync "
+                    f"per execution",
+                    hint="hoist the host work out of the traced "
+                    "body (see KSS701)",
                 )
+            )
 
         # KSS712 — float64 avals anywhere in the program
         allow_f64 = sp.get("allow_f64")
@@ -547,11 +551,22 @@ def _flatten(args: tuple, kwargs: dict) -> "list[Any]":
     return list(jax.tree_util.tree_leaves((args, kwargs)))
 
 
+def _is_host_effect(eqn: Any) -> bool:
+    prim = str(eqn.primitive)
+    if prim in CALLBACK_PRIMS or prim.endswith("_callback"):
+        return True
+    return any(
+        type(e).__name__ in HOST_EFFECTS for e in getattr(eqn, "effects", ())
+    )
+
+
 def _walk_prims(jaxpr: Any, depth: int = 0):
-    """(primitive name, depth) for every eqn, recursing into sub-jaxprs
-    (scan/while/cond bodies, closed or open)."""
+    """(primitive name, depth) for every host-effect eqn (see
+    HOST_EFFECTS), recursing into sub-jaxprs (scan/while/cond bodies,
+    closed or open)."""
     for eqn in jaxpr.eqns:
-        yield str(eqn.primitive), depth
+        if _is_host_effect(eqn):
+            yield str(eqn.primitive), depth
         for sub in _sub_jaxprs(eqn):
             yield from _walk_prims(sub, depth + 1)
 
@@ -762,12 +777,12 @@ AUDITOR = JaxprAuditor()
 
 
 def fingerprint_path(cache_dir: "str | None" = None) -> str:
-    """The baseline file, next to the persistent compile cache (same
-    KSS_JAX_CACHE_DIR override, same per-checkout isolation)."""
-    from ..utils.compilecache import default_cache_dir
+    """The baseline file, next to the persistent compile cache
+    (`compilecache.cache_dir`, same per-checkout isolation)."""
+    from ..utils import compilecache
 
     if cache_dir is None:
-        cache_dir = os.environ.get("KSS_JAX_CACHE_DIR") or default_cache_dir()
+        cache_dir = compilecache.cache_dir()
     return os.path.join(cache_dir, FINGERPRINT_BASENAME)
 
 

@@ -47,40 +47,50 @@ NODE_AXIS_FIELDS = frozenset(
 )
 
 
-def _shard_dataclass(obj, mesh: Mesh):
-    """device_put each field: node-axis fields split over 'nodes',
-    everything else replicated. Nested chex dataclasses recurse."""
+def _shardings(obj, mesh: Mesh):
+    """The dataclass `obj` with each array field replaced by its
+    NamedSharding: node-axis fields split over 'nodes', everything else
+    replicated. Nested chex dataclasses recurse."""
     updates = {}
     for name in obj.__dataclass_fields__:
         leaf = getattr(obj, name)
         if hasattr(leaf, "__dataclass_fields__"):
-            updates[name] = _shard_dataclass(leaf, mesh)
+            updates[name] = _shardings(leaf, mesh)
         elif name in NODE_AXIS_FIELDS:
             spec = P("nodes", *([None] * (leaf.ndim - 1)))
-            updates[name] = jax.device_put(leaf, NamedSharding(mesh, spec))
+            updates[name] = NamedSharding(mesh, spec)
         else:
-            updates[name] = jax.device_put(leaf, NamedSharding(mesh, P()))
+            updates[name] = NamedSharding(mesh, P())
     return obj.replace(**updates)
 
 
-def shard_encoded(enc: EncodedCluster, mesh: Mesh):
-    """Returns (arrays, state0, queue) placed on the mesh: node axis split
-    over 'nodes', pod-axis and vocabulary arrays replicated.
+def encoded_shardings(enc: EncodedCluster, mesh: Mesh):
+    """(arrays, state0, queue) shardings on `mesh` — what
+    `shard_encoded` places, without placing anything (a chip-compile
+    rehearsal lowers against a described mesh that holds no arrays).
 
     The node capacity must divide the 'nodes' mesh axis; encode with
     `node_capacity=k * mesh.shape['nodes']`.
     """
-    import jax.numpy as jnp
-
     n_shards = mesh.shape["nodes"]
     if enc.N % n_shards != 0:
         raise ValueError(
             f"node capacity {enc.N} not divisible by the {n_shards}-way "
             "'nodes' mesh axis; pad with node_capacity="
         )
-    arrays = _shard_dataclass(enc.arrays, mesh)
-    state0 = _shard_dataclass(enc.state0, mesh)
-    queue = jax.device_put(
-        jnp.asarray(enc.queue), NamedSharding(mesh, P())
+    return (
+        _shardings(enc.arrays, mesh),
+        _shardings(enc.state0, mesh),
+        NamedSharding(mesh, P()),
     )
-    return arrays, state0, queue
+
+
+def shard_encoded(enc: EncodedCluster, mesh: Mesh):
+    """Returns (arrays, state0, queue) placed on the mesh: node axis split
+    over 'nodes', pod-axis and vocabulary arrays replicated."""
+    import jax.numpy as jnp
+
+    return jax.device_put(
+        (enc.arrays, enc.state0, jnp.asarray(enc.queue)),
+        encoded_shardings(enc, mesh),
+    )

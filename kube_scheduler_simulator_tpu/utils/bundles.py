@@ -107,15 +107,14 @@ def bundles_enabled() -> bool:
 def bundle_dir() -> str:
     """The bundle directory: ``KSS_BUNDLE_DIR``, defaulting to a
     sibling of ``kss-fingerprints.json`` in the persistent compile
-    cache dir (same ``KSS_JAX_CACHE_DIR`` override, same per-checkout
-    isolation — see the trust model in the module docstring)."""
+    cache dir (`compilecache.cache_dir`, same per-checkout isolation —
+    see the trust model in the module docstring)."""
     override = os.environ.get(DIR_VAR)
     if override:
         return override
-    from .compilecache import default_cache_dir
+    from .compilecache import cache_dir
 
-    cache_dir = os.environ.get("KSS_JAX_CACHE_DIR") or default_cache_dir()
-    return os.path.join(cache_dir, "kss-bundles")
+    return os.path.join(cache_dir(), "kss-bundles")
 
 
 # -- the broker build scope ----------------------------------------------------
@@ -787,16 +786,12 @@ class BundledJit:
         fresh compile fails (the pass keeps the original executable;
         only persistence is skipped)."""
         import jax
+        from jax.experimental.compilation_cache import compilation_cache
 
-        try:
-            from jax._src import compilation_cache as _cc
-        except ImportError:  # pragma: no cover — private-module drift
-            _cc = None
         prev = jax.config.jax_enable_compilation_cache
         try:
             jax.config.update("jax_enable_compilation_cache", False)
-            if _cc is not None:
-                _cc.reset_cache()
+            compilation_cache.reset_cache()
             compiled = (
                 self._jitted.trace(*args, **kwargs)
                 .lower()
@@ -809,8 +804,8 @@ class BundledJit:
         finally:
             try:
                 jax.config.update("jax_enable_compilation_cache", prev)
-                if _cc is not None:
-                    _cc.reset_cache()  # re-evaluate with the restored flag
+                # re-evaluate with the restored flag
+                compilation_cache.reset_cache()
             except Exception:  # noqa: BLE001 — never leave config torn
                 pass
         telemetry.instant("bundle.mint_recompile", label=self._label)

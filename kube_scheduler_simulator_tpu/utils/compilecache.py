@@ -1,10 +1,16 @@
 """The one definition of the repo-local persistent XLA compile cache.
 
-bench.py, tests/conftest.py, and tools/config5_e2e.py all want the same
-thing: repeat compiles of an identical program (across processes AND
-across judge re-runs) are disk hits, not fresh XLA compiles. Before this
-helper each carried its own copy and they drifted (different thresholds,
-only conftest honoring the KSS_JAX_CACHE_DIR override — code-review r5).
+bench.py, tests/conftest.py, the serving path and the tools all want the
+same thing: repeat compiles of an identical program (across processes
+AND across runs) are disk hits, not fresh XLA compiles.
+
+`cache_dir()` is the one answer to "where": JAX's own
+`JAX_COMPILATION_CACHE_DIR` when it is set — so the cache can be placed
+from outside — and otherwise a fixed default. The compile cache, the AOT
+bundles (utils/bundles.py), the program ledger (utils/ledger.py) and the
+KSS715 fingerprint baseline (analysis/jaxpr_audit.py) all live under it.
+The path is part of the cache's key, so it never derives from a temp
+name, a pid or the time.
 
 The default directory is `.jax_cache` at the repo root (gitignored):
 per-checkout isolation — a world-shared /tmp dir would break on
@@ -58,27 +64,31 @@ def capacity_buckets(
 
 
 def default_cache_dir(repo_root: "str | None" = None) -> str:
-    """The cache directory `enable_compile_cache` uses absent the
-    KSS_JAX_CACHE_DIR override: `<repo_root>/.jax_cache` when the root
-    is a writable directory, else the per-user `~/.cache/kss-jax` (the
-    package may live in a read-only site-packages tree)."""
+    """The cache directory absent JAX_COMPILATION_CACHE_DIR:
+    `<repo_root>/.jax_cache` when the root is a writable directory, else
+    the per-user `~/.cache/kss-jax` (the package may live in a read-only
+    site-packages tree)."""
     root = _REPO_ROOT if repo_root is None else repo_root
     if os.path.isdir(root) and os.access(root, os.W_OK):
         return os.path.join(root, ".jax_cache")
     return os.path.join(os.path.expanduser("~"), ".cache", "kss-jax")
 
 
+def cache_dir() -> str:
+    """The persistent cache directory: `JAX_COMPILATION_CACHE_DIR` when
+    set, else `default_cache_dir()`."""
+    return os.environ.get("JAX_COMPILATION_CACHE_DIR") or default_cache_dir()
+
+
 def enable_compile_cache(min_compile_time_secs: float = 0.1) -> None:
-    """Point JAX at the persistent compile cache. Honors the
-    KSS_JAX_CACHE_DIR env override (what conftest always did). Safe to
+    """Point JAX at the persistent compile cache (`cache_dir()`). Safe to
     call repeatedly; failures are swallowed — the cache is an
     optimization, never a correctness dependency."""
     try:
         import jax
 
         jax.config.update(  # type: ignore[no-untyped-call]
-            "jax_compilation_cache_dir",
-            os.environ.get("KSS_JAX_CACHE_DIR", default_cache_dir()),
+            "jax_compilation_cache_dir", cache_dir()
         )
         jax.config.update(  # type: ignore[no-untyped-call]
             "jax_persistent_cache_min_compile_time_secs",

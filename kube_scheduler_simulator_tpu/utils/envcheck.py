@@ -137,7 +137,6 @@ KNOWN: "dict[str, Validator]" = {
     # chunk-invariant, so this is a pure performance knob (default 64)
     "KSS_GANG_CHUNK": _int_validator(1),
     "KSS_NO_SPECULATIVE_COMPILE": _bool_validator,
-    "KSS_JAX_CACHE_DIR": _path_validator,
     # the persistent AOT bundle store (utils/bundles.py): serialize
     # every broker-jitted program's compiled executable to disk and
     # deserialize it on the next boot instead of re-lowering; the

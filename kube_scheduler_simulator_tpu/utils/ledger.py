@@ -24,7 +24,7 @@ program records, keyed ``(site label, compile fingerprint)``:
   * a **sampled warm device wall**: ``KSS_PROGRAM_TIMING_SAMPLE=N``
     blocks on the result every Nth call (first/compile call excluded)
     — off by default so the async hot path never synchronizes;
-  * derived per-program **MFU** (``utils/metrics.PEAK_FLOPS_PER_S``)
+  * derived per-program **MFU** (``utils/metrics.PEAKS``)
     on known accelerators, from the cost-model FLOPs over the sampled
     warm wall;
   * per-session call attribution via the telemetry session labels.
@@ -335,7 +335,8 @@ class ProgramLedger:
         """The full ledger document (``GET /api/v1/debug/programs`` and
         the persisted file). `session` filters to programs that session's
         passes actually dispatched (the nested per-session route)."""
-        platform = _platform()
+        device = _device()
+        platform = device.platform if device is not None else ""
         from . import metrics as metrics_mod
 
         programs: list[dict] = []
@@ -372,8 +373,8 @@ class ProgramLedger:
                         if warm_mean is not None
                         else None,
                     },
-                    "mfu": metrics_mod.mfu(rec.flops, warm_mean, platform)
-                    if warm_mean
+                    "mfu": metrics_mod.mfu(rec.flops, warm_mean, device)
+                    if warm_mean and device is not None
                     else None,
                     "sessions": dict(rec.sessions),
                     "degraded": rec.degraded,
@@ -462,13 +463,15 @@ class ProgramLedger:
         return drift
 
 
-def _platform() -> str:
+def _device():
+    """The default device, or None when no backend starts (a dead
+    backend still has a ledger)."""
     try:
         import jax
 
-        return jax.devices()[0].platform
-    except Exception:  # noqa: BLE001 — a dead backend still has a ledger
-        return ""
+        return jax.devices()[0]
+    except Exception:  # noqa: BLE001
+        return None
 
 
 def _observe_fingerprint(label: str, args: tuple) -> str:
@@ -542,11 +545,11 @@ def aot_probe(jitted: Any, args: tuple, kwargs: "dict | None" = None):
 
 def ledger_path(cache_dir: "str | None" = None) -> str:
     """The baseline file, next to the persistent compile cache and the
-    KSS715 fingerprint baseline (same KSS_JAX_CACHE_DIR override)."""
-    from .compilecache import default_cache_dir
+    KSS715 fingerprint baseline (`compilecache.cache_dir`)."""
+    from . import compilecache
 
     if cache_dir is None:
-        cache_dir = os.environ.get("KSS_JAX_CACHE_DIR") or default_cache_dir()
+        cache_dir = compilecache.cache_dir()
     return os.path.join(cache_dir, LEDGER_BASENAME)
 
 

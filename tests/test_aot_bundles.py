@@ -177,7 +177,7 @@ def test_fingerprint_drift_bypasses_to_fresh_compile(
     # every bundled site compiles a different program
     cache_dir = tmp_path / "cache"
     cache_dir.mkdir()
-    monkeypatch.setenv("KSS_JAX_CACHE_DIR", str(cache_dir))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(cache_dir))
     labels = set()
     for path in files:
         with open(path, "rb") as f:

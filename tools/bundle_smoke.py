@@ -123,7 +123,7 @@ def main() -> int:
         JAX_PLATFORMS="cpu",
         KSS_AOT_BUNDLES="1",
         KSS_BUNDLE_DIR=bundle_dir,
-        KSS_JAX_CACHE_DIR=cache_dir,
+        JAX_COMPILATION_CACHE_DIR=cache_dir,
         # a deterministic program set: no background speculative builds
         # racing the exit flush
         KSS_NO_SPECULATIVE_COMPILE="1",

@@ -127,7 +127,7 @@ def main() -> int:
         JAX_PLATFORMS="cpu",
         KSS_LOCK_CHECK="1",
         KSS_NO_SPECULATIVE_COMPILE="1",
-        KSS_JAX_CACHE_DIR=cache_dir,
+        JAX_COMPILATION_CACHE_DIR=cache_dir,
         # the durability plane under test: per-write journaling with
         # inline successor shipping, a fast replication cadence, and
         # the HTTP transport forced (distinct non-shared session dirs)

@@ -134,7 +134,7 @@ def main() -> int:
         os.environ,
         JAX_PLATFORMS="cpu",
         KSS_AOT_BUNDLES="1",
-        KSS_JAX_CACHE_DIR=cache_dir,
+        JAX_COMPILATION_CACHE_DIR=cache_dir,
         KSS_NO_SPECULATIVE_COMPILE="1",
     )
     env.pop("KSS_WORKER_ID", None)  # the router assigns identities
