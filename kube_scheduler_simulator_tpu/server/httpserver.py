@@ -147,6 +147,37 @@ WATCH_KINDS = {
     "namespaces": ("namespaces", "namespaceLastResourceVersion"),
 }
 
+# API routes that carry no `http.request` span: the trace export must not
+# hold an open span of its own, and a stream never ends to close one
+UNSPANNED_ROUTES = ("GET debug/trace", "GET events", "GET listwatchresources")
+
+
+def route_template(method: str, path: str) -> "str | None":
+    """The route a request addresses, as a low-cardinality template for
+    its `http.request` span: the method and the path with its variable
+    segments replaced — session and checkpoint ids, object namespaces
+    and names, extender ids (`PUT /api/v1/resources/pods`,
+    `DELETE /api/v1/resources/pods/{namespace}/{name}`). None for the
+    routes in `UNSPANNED_ROUTES`."""
+    parts = [p for p in urlparse(path).path.split("/") if p]
+    if parts[:2] != ["api", "v1"]:
+        if parts in ([], ["ui"]):
+            return f"{method} /" + "/".join(parts)
+        return f"{method} (not found)"
+    prefix, rest = ["api", "v1"], parts[2:]
+    if rest[:1] == ["sessions"] and len(rest) > 1:
+        prefix += ["sessions", "{id}"]
+        rest = rest[2:]
+    if rest[:2] == ["admin", "checkpoints"] and len(rest) > 2:
+        rest = rest[:2] + ["{id}"]
+    elif rest[:1] == ["resources"] and len(rest) > 2:
+        rest = rest[:2] + (["{namespace}", "{name}"] if len(rest) > 3 else ["{name}"])
+    elif rest[:1] == ["extender"] and len(rest) > 2:
+        rest = rest[:2] + ["{id}"]
+    if f"{method} " + "/".join(rest) in UNSPANNED_ROUTES:
+        return None
+    return f"{method} /" + "/".join(prefix + rest)
+
 
 @locking.guard_inferred
 class SimulatorServer:
@@ -477,8 +508,21 @@ def _make_handler(server: SimulatorServer):
                     self.headers.get("traceparent")
                 )
             if tid is None:
-                return self._route_inner(method)
+                return self._route_spanned(method)
             with telemetry.trace_context(tid):
+                return self._route_spanned(method)
+
+        def _route_spanned(self, method: str):
+            # one `http.request` span per API call while tracing is on,
+            # named by its route template, never by an object's name
+            route = (
+                route_template(method, self.path)
+                if telemetry.enabled()
+                else None
+            )
+            if route is None:
+                return self._route_inner(method)
+            with telemetry.span("http.request", method=method, route=route):
                 return self._route_inner(method)
 
         def _route_inner(self, method: str):

@@ -236,6 +236,10 @@ class SchedulerService:
         # monotonic pass sequence (telemetry causality): advanced under
         # the schedule lock, so ids order exactly like passes do
         self._pass_seq = 0
+        # the current pass's device.execute window: launch and results
+        # on the host (`_mark_launch`, and the finish's fetch)
+        self._launch_s: "float | None" = None
+        self._fetched_s: "float | None" = None
         # -- execution ladder state (docs/resilience.md) -----------------
         # the rung this service currently dispatches on:
         #   "device" — the healthy default;
@@ -494,19 +498,33 @@ class SchedulerService:
 
     def _schedule_gang_locked(self, config, record: bool, window=None):
         """Gang pass: encode, run to fixpoint, write results back."""
-        t0 = time.perf_counter()
         disp = self._gang_dispatch(config, record, window)
-        if disp is not None:
-            telemetry.complete(
-                "device.execute",
-                t0,
-                time.perf_counter(),
-                tid=telemetry.DEVICE_TID,
-                mode="gang",
-            )
         if disp is None:
             return {}, 0, ([] if record else None)
-        return self._gang_finish(disp, record)
+        out = self._gang_finish(disp, record)
+        self._device_execute(mode="gang")
+        return out
+
+    def _mark_launch(self) -> None:
+        """The pass's program is about to launch: encode and engine
+        acquisition are done. Opens the pass's `device.execute` window,
+        which the finish closes when its fetch returns (`_fetched_s`)."""
+        self._launch_s = time.perf_counter()
+        self._fetched_s = None
+
+    def _device_execute(self, **attrs) -> None:
+        """Emit the pass's `device.execute` window on the synthetic device
+        track: from the launch to the results on the host, so it holds
+        the device's run and none of the encode or the engine lookup.
+        Passes are serialized (`_schedule_lock` is held from dispatch to
+        finish), so one pair of marks per service suffices; a pass that
+        never launched or never fetched emits nothing."""
+        t0, t1 = self._launch_s, self._fetched_s
+        self._launch_s = self._fetched_s = None
+        if t0 is not None and t1 is not None:
+            telemetry.complete(
+                "device.execute", t0, t1, tid=telemetry.DEVICE_TID, **attrs
+            )
 
     def _fire_device_dispatch(self) -> None:
         """The fault plane's device-dispatch site (``device_error`` /
@@ -755,11 +773,19 @@ class SchedulerService:
         returns an opaque tuple for `_gang_finish`, or None when nothing
         is schedulable. Everything downstream of this (decode,
         write-backs) is deferrable — the async pipeline's split point."""
-        from ..engine.gang import GangScheduler
-
         enc = self._encode_current(config)
         if enc is None:
             return None
+        # engine lease, broker lookup or compile wait, retarget, and the
+        # launch with its host-to-device copy of the encoding
+        with telemetry.span("pass.dispatch"):
+            return self._gang_launch(enc, config, record, window)
+
+    def _gang_launch(self, enc, config, record: bool, window=None):
+        """Acquire the gang engine for `enc` and launch its run (see
+        `_gang_dispatch_once`)."""
+        from ..engine.gang import GangScheduler
+
         self._fire_device_dispatch()
         chunk = gang_chunk()
         # the window joins the broker key as the CANONICAL chunk-rounded
@@ -793,6 +819,7 @@ class SchedulerService:
             )
             # jit is lazy: the first drive IS the XLA compile, so the
             # broker's miss wall time is the true request-thread stall
+            self._mark_launch()
             if record:
                 g.run_recorded()
             else:
@@ -811,6 +838,7 @@ class SchedulerService:
             # runs the engine, so the finish path is identical)
             return (enc, self._eager_fallback(build, e))
         if not holder.get("ran"):
+            self._mark_launch()
             gang.retarget(enc)
             if record:
                 gang.run_recorded()
@@ -843,71 +871,80 @@ class SchedulerService:
 
         enc, gang = disp
         t_decode = time.perf_counter()
-        if record and getattr(gang, "_kss_eager_fallback", False):
-            # a degraded pass's record decode lazily builds its replay
-            # programs (_recorder/_assemble_trace) — those compiles must
-            # run un-jitted too, or the "slow but completes" guarantee
-            # dies right here on the same wedged compiler
-            with eager_execution():
-                results = gang.results()
-        else:
-            results = gang.results() if record else None
         # preemption victims: pre-bound pods the preempt phase evicted.
         # They are NOT in placements (decode covers queued pods only), so
         # diff the full [P] assignment exactly like the sequential path —
         # upstream preemption deletes victims through the API. One
-        # device_get fetches both sides of the diff; placements decode
-        # reads the already-landed `after` rows (no second sync).
-        before, after = jax.device_get(
-            (enc.state0.assignment, gang._final_state.assignment)
-        )
-        before = np.asarray(before)
-        after = np.asarray(after)
-        placements = gang.enc.decode_assignment(after)
-        rounds = int(np.asarray(gang._rounds))
-        # booked here, not at dispatch: the rounds scalar stays on
-        # device until this finish-path fetch (async overlap depends on
-        # the dispatch staying sync-free)
-        self.metrics.record_gang(fixpoint_rounds=rounds)
-        for p_idx in np.nonzero((before >= 0) & (after < 0))[0]:
-            ns, name = enc.pod_keys[int(p_idx)]
-            self.store.delete("pods", name, ns)
-        if results is not None:
-            # the sequential write-back rule: last record per pod wins
-            # (a nominated pod's retry overwrites its first record)
-            for res in results:
-                patch: dict = {
-                    "metadata": {
-                        "name": res.pod_name,
-                        "namespace": res.pod_namespace,
-                        "annotations": res.to_annotations(),
-                    }
-                }
-                sel = placements.get((res.pod_namespace, res.pod_name), "")
-                if sel:
-                    patch["spec"] = {"nodeName": sel}
-                if (
-                    self.store.get("pods", res.pod_name, res.pod_namespace)
-                    is not None
-                ):
-                    self.store.apply("pods", patch)
-        else:
-            for (ns, name), node_name in placements.items():
-                if not node_name:
-                    continue
-                if self.store.get("pods", name, ns) is not None:
-                    self.store.apply(
-                        "pods",
-                        {
-                            "metadata": {"name": name, "namespace": ns},
-                            "spec": {"nodeName": node_name},
-                        },
-                    )
+        # device_get fetches both sides of the diff and the rounds
+        # scalar: the host's one block on the device program; placements
+        # decode reads the already-landed `after` rows (no second sync).
+        with telemetry.span("pass.device_wait"):
+            before, after, rounds = jax.device_get(
+                (
+                    enc.state0.assignment,
+                    gang._final_state.assignment,
+                    gang._rounds,
+                )
+            )
+        self._fetched_s = time.perf_counter()  # results on the host
+        with telemetry.span("pass.writeback"):
+            if record and getattr(gang, "_kss_eager_fallback", False):
+                # a degraded pass's record decode lazily builds its replay
+                # programs (_recorder/_assemble_trace) — those compiles
+                # must run un-jitted too, or the "slow but completes"
+                # guarantee dies right here on the same wedged compiler
+                with eager_execution():
+                    results = gang.results()
+            else:
+                results = gang.results() if record else None
+            before = np.asarray(before)
+            after = np.asarray(after)
+            placements = gang.enc.decode_assignment(after)
+            rounds = int(np.asarray(rounds))
+            # booked here, not at dispatch: the rounds scalar stays on
+            # device until this finish-path fetch (async overlap depends
+            # on the dispatch staying sync-free)
+            self.metrics.record_gang(fixpoint_rounds=rounds)
+            for p_idx in np.nonzero((before >= 0) & (after < 0))[0]:
+                ns, name = enc.pod_keys[int(p_idx)]
+                self.store.delete("pods", name, ns)
+            if results is not None:
+                self._write_back_records(results, placements)
+            else:
+                for (ns, name), node_name in placements.items():
+                    if not node_name:
+                        continue
+                    if self.store.get("pods", name, ns) is not None:
+                        self.store.apply(
+                            "pods",
+                            {
+                                "metadata": {"name": name, "namespace": ns},
+                                "spec": {"nodeName": node_name},
+                            },
+                        )
         self.metrics.record_phase_seconds(
             decode=time.perf_counter() - t_decode
         )
         self._fleet_sample(enc, gang._final_state, "gang")
         return placements, rounds, results
+
+    def _write_back_records(self, results, placements: dict) -> None:
+        """The gang record pass's write-back, by the sequential rule:
+        last record per pod wins (a nominated pod's retry overwrites its
+        first record)."""
+        for res in results:
+            patch: dict = {
+                "metadata": {
+                    "name": res.pod_name,
+                    "namespace": res.pod_namespace,
+                    "annotations": res.to_annotations(),
+                }
+            }
+            sel = placements.get((res.pod_namespace, res.pod_name), "")
+            if sel:
+                patch["spec"] = {"nodeName": sel}
+            if self.store.get("pods", res.pod_name, res.pod_namespace) is not None:
+                self.store.apply("pods", patch)
 
     def _encode_current(self, config) -> "object | None":
         """Encode the store's current pending state under the pass's
@@ -920,6 +957,18 @@ class SchedulerService:
         `encode_cluster` covers everything the delta path can't prove
         exact. Encode wall time + the path taken land in the metrics'
         phase breakdown."""
+        with telemetry.span("pass.encode") as encode_span:
+            enc, mode = self._encode_tiers(config)
+            encode_span.set(mode=mode)
+        if enc is not None and mode != "cached":
+            # cold-start accounting (utils/ledger.py): the process's
+            # first real cluster encode just landed (latched)
+            ledger_mod.COLD_START.mark("firstEncode")
+        return enc
+
+    def _encode_tiers(self, config) -> tuple:
+        """(encoding or None, the tier that served it) for
+        `_encode_current`."""
         t0 = time.perf_counter()
         # the dtype policy is re-read each pass, and both cache tiers are
         # policy-aware: the LRU keys on the policy name, and the delta
@@ -936,10 +985,7 @@ class SchedulerService:
             with self._lock:
                 self.last_encode_info = {"mode": "cached"}
             self.metrics.record_encode("cached", time.perf_counter() - t0)
-            telemetry.complete(
-                "pass.encode", t0, time.perf_counter(), mode="cached"
-            )
-            return cached
+            return cached, "cached"
         self._delta.policy = policy
         enc, info = self._delta.encode(self.store, config)
         if info.get("reason") == "dtype-policy-change":
@@ -948,14 +994,7 @@ class SchedulerService:
         with self._lock:
             self.last_encode_info = info
         self.metrics.record_encode(info["mode"], time.perf_counter() - t0)
-        telemetry.complete(
-            "pass.encode", t0, time.perf_counter(), mode=info["mode"]
-        )
-        if enc is not None:
-            # cold-start accounting (utils/ledger.py): the process's
-            # first real cluster encode just landed (latched)
-            ledger_mod.COLD_START.mark("firstEncode")
-        return enc
+        return enc, info["mode"]
 
     # -- predictive compilation --------------------------------------------
 
@@ -1103,19 +1142,6 @@ class SchedulerService:
             raise
 
         def finish() -> int:
-            # the in-flight window: device execution of THIS pass ran
-            # from dispatch until now, overlapping whatever host work
-            # the caller did in between — the one span shape that lands
-            # on the synthetic device track and can OVERLAP host spans
-            telemetry.complete(
-                "device.execute",
-                t0,
-                time.perf_counter(),
-                tid=telemetry.DEVICE_TID,
-                pass_id=pass_id,
-                mode=mode,
-                trace=armed_trace,
-            )
             with self._session_scope(), telemetry.trace_context(
                 armed_trace
             ), telemetry.pass_context(
@@ -1125,6 +1151,12 @@ class SchedulerService:
                 scheduled = sum(
                     1 for r in results if r.status == "Scheduled"
                 )
+            # the in-flight window: device execution of THIS pass ran
+            # from its launch until the resolve fetched its results,
+            # overlapping whatever host work the caller did in between —
+            # the one span shape that lands on the synthetic device
+            # track and can OVERLAP host spans
+            self._device_execute(pass_id=pass_id, mode=mode, trace=armed_trace)
             # distinct pods, like the synchronous pass (a preempting pod
             # yields two records); the explicit pass_id keeps the
             # latency histogram's exemplar causal outside pass_context
@@ -1174,15 +1206,6 @@ class SchedulerService:
             raise
 
         def finish() -> int:
-            telemetry.complete(
-                "device.execute",
-                t0,
-                time.perf_counter(),
-                tid=telemetry.DEVICE_TID,
-                pass_id=pass_id,
-                mode="gang",
-                trace=armed_trace,
-            )
             if disp is None:
                 self.metrics.record(
                     metrics_mod.PassRecord(
@@ -1197,6 +1220,7 @@ class SchedulerService:
                 pass_id
             ), telemetry.span("pass.gang.resolve", pass_id=pass_id):
                 placements, rounds, _results = self._gang_finish(disp, record)
+            self._device_execute(pass_id=pass_id, mode="gang", trace=armed_trace)
             scheduled = sum(1 for v in placements.values() if v)
             self.metrics.record(
                 metrics_mod.PassRecord(
@@ -1213,22 +1237,18 @@ class SchedulerService:
         return SchedulingPassHandle(self, "gang", finish, info, pass_id=pass_id)
 
     def _schedule_locked(self, config) -> list[PodSchedulingResult]:
-        # the synchronous pass's device window, on the synthetic device
-        # track like the async handle's (encode + engine execution live
-        # inside the dispatch): pass/session/trace ids stamp from the
-        # ambient contexts — the request thread runs the whole pass
-        t0 = time.perf_counter()
         disp = self._seq_dispatch(config)
         if disp is None:
             return []  # nothing schedulable: no device work to record
-        telemetry.complete(
-            "device.execute",
-            t0,
-            time.perf_counter(),
-            tid=telemetry.DEVICE_TID,
-            mode="extender" if config.extenders else "sequential",
+        results = self._seq_finish(disp)
+        # the synchronous pass's device window, on the synthetic device
+        # track like the async handle's: pass/session/trace ids stamp
+        # from the ambient contexts — the request thread runs the whole
+        # pass
+        self._device_execute(
+            mode="extender" if config.extenders else "sequential"
         )
-        return self._seq_finish(disp)
+        return results
 
     def _seq_dispatch(self, config):
         """One sequential dispatch under the execution ladder (see
@@ -1247,6 +1267,12 @@ class SchedulerService:
         enc = self._encode_current(config)
         if enc is None:
             return None
+        with telemetry.span("pass.dispatch"):
+            return self._seq_launch(enc, config)
+
+    def _seq_launch(self, enc, config):
+        """Acquire the sequential engine for `enc` and launch its run (see
+        `_seq_dispatch_once`)."""
         self._fire_device_dispatch()
         if config.extenders:
             # host-callback loop: device segments + extender HTTP calls,
@@ -1286,6 +1312,7 @@ class SchedulerService:
                     self.metrics.record_engine_build(holder["built_s"])
                 else:
                     ext_sched.retarget(enc, ext_service)
+            self._mark_launch()
             t0 = time.perf_counter()
             results = ext_sched.run()
             self.metrics.record_phase_seconds(execute=time.perf_counter() - t0)
@@ -1308,6 +1335,7 @@ class SchedulerService:
             s = BatchedScheduler(enc, record=True, strict=True)
             # jit is lazy: the first run IS the XLA compile, so the
             # broker's miss wall time is the true request-thread stall
+            self._mark_launch()
             s.run()
             holder["ran"] = True
             return s
@@ -1321,6 +1349,7 @@ class SchedulerService:
         except CompileUnavailable as e:
             return ("batch", enc, self._eager_fallback(build, e), None)
         if not holder.get("ran"):
+            self._mark_launch()
             sched.retarget(enc)
             sched.run()
         dt = time.perf_counter() - t0
@@ -1383,6 +1412,7 @@ class SchedulerService:
             queue = np.concatenate(
                 [queue, np.full(bucket - len(queue), -1, np.int32)]
             )
+        self._mark_launch()
         out = plane.submit(
             sig, engine, queue,
             metrics=self.metrics, session_id=self.session_id,
@@ -1442,6 +1472,7 @@ class SchedulerService:
         # tensor (the gang program's queue encoding — fixed length, so
         # bucket-compatible sessions stack without padding logic)
         order, _ = gang.order_arrays()
+        self._mark_launch()
         out = plane.submit(
             sig, gang, np.asarray(order, np.int32),
             metrics=self.metrics, session_id=self.session_id,
@@ -1470,38 +1501,52 @@ class SchedulerService:
         # under the state lock by restart() — KSS6xx)
         with self._lock:
             ext_service = self.extender_service
-        t0 = time.perf_counter()
-        if kind == "ext":
-            final_assignment = engine.final_state.assignment
-        elif getattr(engine, "_kss_eager_fallback", False):
-            # same trap the gang record decode has: any jit `results()`
-            # creates lazily must stay on a degraded pass's eager rung
-            # (today the sequential engine jits everything in __init__,
-            # but this guard keeps that an implementation detail)
-            with eager_execution():
-                results = engine.results()
-            final_assignment = engine._final_state.assignment
-        else:
-            results = engine.results()
-            final_assignment = engine._final_state.assignment
-        self.metrics.record_phase_seconds(decode=time.perf_counter() - t0)
-
+        t_decode = time.perf_counter()
+        final_state = engine.final_state if kind == "ext" else engine._final_state
         # preemption victims: pre-bound pods that lost their node (upstream
         # preemption deletes victims through the API). ONE device_get for
-        # both sides of the diff instead of two separate host syncs; the
-        # placements decode reads the already-landed `after` rows.
-        t_decode = time.perf_counter()
-        before, after = jax.device_get((enc.state0.assignment, final_assignment))
-        before = np.asarray(before)
-        after = np.asarray(after)
-        placements = enc.decode_assignment(after)
-        for p_idx in np.nonzero((before >= 0) & (after < 0))[0]:
-            ns, name = enc.pod_keys[int(p_idx)]
-            self.store.delete("pods", name, ns)
+        # both sides of the diff instead of two separate host syncs: the
+        # host's block on the device program; the placements decode reads
+        # the already-landed `after` rows.
+        with telemetry.span("pass.device_wait"):
+            before, after = jax.device_get(
+                (enc.state0.assignment, final_state.assignment)
+            )
+        self._fetched_s = time.perf_counter()  # results on the host
+        with telemetry.span("pass.writeback"):
+            # the extender loop ran synchronously and returned its
+            # records with the dispatch
+            if kind != "ext" and getattr(engine, "_kss_eager_fallback", False):
+                # same trap the gang record decode has: any jit
+                # `results()` creates lazily must stay on a degraded
+                # pass's eager rung (today the sequential engine jits
+                # everything in __init__, but this guard keeps that an
+                # implementation detail)
+                with eager_execution():
+                    results = engine.results()
+            elif kind != "ext":
+                results = engine.results()
+            before = np.asarray(before)
+            after = np.asarray(after)
+            placements = enc.decode_assignment(after)
+            for p_idx in np.nonzero((before >= 0) & (after < 0))[0]:
+                ns, name = enc.pod_keys[int(p_idx)]
+                self.store.delete("pods", name, ns)
+            self._write_back_seq(results, placements, ext_service)
+        self.metrics.record_phase_seconds(
+            decode=time.perf_counter() - t_decode
+        )
+        self._fleet_sample(
+            enc,
+            engine.final_state if kind == "ext" else engine._final_state,
+            "extender" if kind == "ext" else "sequential",
+        )
+        return results
 
-        # write results back onto the pod objects (last record per pod wins
-        # — a nominated pod's retry attempt overwrites its first record,
-        # like the reference's sequential annotation updates)
+    def _write_back_seq(self, results, placements: dict, ext_service) -> None:
+        """Write results back onto the pod objects (last record per pod
+        wins — a nominated pod's retry attempt overwrites its first
+        record, like the reference's sequential annotation updates)."""
         for res in results:
             annotations = res.to_annotations()
             annotations.update(
@@ -1522,15 +1567,6 @@ class SchedulerService:
             # flushed results are purged, like the reference reflector's
             # DeleteData after AddStoredResultToPod (storereflector.go:70-119)
             ext_service.delete_data(res.pod_namespace, res.pod_name)
-        self.metrics.record_phase_seconds(
-            decode=time.perf_counter() - t_decode
-        )
-        self._fleet_sample(
-            enc,
-            engine.final_state if kind == "ext" else engine._final_state,
-            "extender" if kind == "ext" else "sequential",
-        )
-        return results
 
 
 @locking.guard_inferred

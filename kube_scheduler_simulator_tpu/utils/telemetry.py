@@ -39,13 +39,21 @@ name the pass that armed it.
 Cost model: tracing is **off by default** and near-zero-cost when off —
 `span()` returns a shared no-op context manager after one env probe
 (`KSS_TRACE`, cached on the raw string exactly like
-utils/faultinject.py), no allocation, no lock on the ring.
-`tools/perf_smoke.py` gates the disabled-path overhead. The ring
+utils/faultinject.py), no allocation, no lock on the ring, no JAX
+import. tests/test_telemetry_mirror.py pins that off path. The ring
 capacity is `KSS_TRACE_RING_CAP` events (default 65536); past it the
 oldest events are overwritten — the flight-recorder contract.
 
 Timestamps are `time.perf_counter()` microseconds: monotonic, shared
 across threads, the unit Chrome trace events use (`ts`/`dur`).
+
+Profiler mirror: while a recorder is active, every `span()` also holds
+a `jax.profiler.TraceAnnotation` of the same name for its lifetime, so
+a `jax.profiler` capture shows the program's spans as host events on
+the capture's own clock, beside the device's operations. The mirrored
+events carry the stat ``cat=kss`` (`MIRROR_STAT`), which tells them
+from XLA's own host events. `complete()` windows are emitted after the
+fact and are not mirrored.
 
 Distributed tracing (docs/observability.md "Distributed tracing"): the
 fleet router mints a W3C-traceparent-style context per inbound request
@@ -77,6 +85,10 @@ ENV_VAR = "KSS_TRACE"
 CAP_VAR = "KSS_TRACE_RING_CAP"
 PROPAGATE_VAR = "KSS_TRACE_PROPAGATE"
 DEFAULT_RING_CAP = 65536
+
+# the stat every profiler-mirrored span carries: ``cat=kss``, the
+# category its ring events carry too
+MIRROR_STAT = ("cat", "kss")
 
 # the synthetic track for non-thread-shaped intervals (the async
 # pipeline's in-flight device-execute windows). Python thread idents are
@@ -425,33 +437,57 @@ class _NullSpan:
     def __exit__(self, *exc):
         return False
 
+    def set(self, **attrs) -> None:
+        pass
+
 
 _NULL_SPAN = _NullSpan()
 
+def _mirror(name: str):
+    """Enter and return a profiler annotation named `name`. JAX is
+    imported here, on the live path only."""
+    from jax.profiler import TraceAnnotation
+
+    ann = TraceAnnotation(name, **{MIRROR_STAT[0]: MIRROR_STAT[1]})
+    ann.__enter__()
+    return ann
+
 
 class _LiveSpan:
-    __slots__ = ("_rec", "_name", "_a")
+    __slots__ = ("_rec", "_name", "_a", "_b", "_ann")
 
     def __init__(self, rec: SpanRecorder, name: str, args: dict):
         self._rec = rec
         self._name = name
         self._a = args
+        self._b = None
+        self._ann = None
+
+    def set(self, **attrs) -> None:
+        """Add attributes known only inside the span (an encode's mode):
+        the E event carries them, and so does its B partner in the ring.
+        The B event gets a new args dict, never a mutated one, so a
+        concurrent export never sees a dict change size."""
+        self._a = {**self._a, **attrs}
+        if self._b is not None:
+            self._b["args"] = self._a
 
     def __enter__(self):
-        self._rec.emit(
-            {
-                "ph": "B",
-                "name": self._name,
-                "cat": "kss",
-                "ts": _now_us(),
-                "pid": _PID,
-                "tid": threading.get_ident(),
-                "args": self._a,
-            }
-        )
+        self._b = {
+            "ph": "B",
+            "name": self._name,
+            "cat": "kss",
+            "ts": _now_us(),
+            "pid": _PID,
+            "tid": threading.get_ident(),
+            "args": self._a,
+        }
+        self._rec.emit(self._b)
+        self._ann = _mirror(self._name)
         return self
 
     def __exit__(self, *exc):
+        self._ann.__exit__(None, None, None)
         self._rec.emit(
             {
                 "ph": "E",
@@ -468,8 +504,10 @@ class _LiveSpan:
 
 def span(name: str, pass_id: "int | None" = None, **attrs):
     """A context manager recording `name` as a B/E span on the calling
-    thread's track, stamped with the current (or given) pass id. When
-    tracing is off this returns a shared no-op immediately."""
+    thread's track, stamped with the current (or given) pass id, and
+    mirrored into any running `jax.profiler` capture. When tracing is
+    off this returns a shared no-op immediately. Both kinds have
+    `set(**attrs)` for attributes known only before the span ends."""
     rec = active()
     if rec is None:
         return _NULL_SPAN
