@@ -538,17 +538,150 @@ TRIVIAL_PREFILTER.add("NodePorts")
 
 
 # ---------------------------------------------------------------------------
-# PodTopologySpread  (oracle: spread_pre_filter/spread_filter/
-# spread_pre_score/spread_score/spread_normalize). The per-topology-value
-# match counts are reduced on-device each step by scatter-adds keyed on
-# state.assignment — the oracle's PreFilter/PreScore dict-building loops
-# become two scatters and two gathers.
+# Topology histograms (shared by PodTopologySpread and InterPodAffinity).
+# Every per-pod count of matching pods by node, or by node (key, value)
+# pair, is a contraction of a per-pod 0/1 mask with a one-hot plane built
+# from the state alone: the node each pod is bound to, the pair each node
+# carries per key, the pair of each bound pod's node per term. Reading a
+# histogram back at the nodes is a contraction with the nodes' one-hot
+# pair plane too. The planes do not depend on the incoming pod, so under
+# the gang pass's pod `vmap` they stay unbatched and a chunk's histograms
+# are a few matmuls. Scatter-adds and gathers would give the same
+# integers, but the TPU applies colliding scatter updates one after
+# another, and a gather with per-pod indices runs element by element
+# (docs/performance.md "Topology histograms are contractions"). Operands
+# are int8, accumulation int32: every count and every value is exact.
 # ---------------------------------------------------------------------------
 
 
-def _spread_counts(a: ClusterArrays, s: SchedState, p, ctype, ckey, cpairs):
-    """[T, N] — per constraint, matching bound pods on each node (same
-    namespace as pod p, not deleted; oracle _count_matching_pods)."""
+def _onehot(ids, n: int) -> jnp.ndarray:
+    """[..., n] int8 plane, 1 where `ids` equals the column; an id
+    outside [0, n) (an unbound pod's -1) gives a zero row."""
+    cols = jnp.arange(n, dtype=jnp.int32)
+    return (ids.astype(jnp.int32)[..., None] == cols).astype(jnp.int8)
+
+
+def _contract(spec: str, mask, plane) -> jnp.ndarray:
+    """Exact int32 `einsum(spec)` of a 0/1 mask with an int8 plane."""
+    return jnp.einsum(
+        spec, mask.astype(jnp.int8), plane, preferred_element_type=jnp.int32
+    )
+
+
+def _pod_nodes(a: ClusterArrays, s: SchedState) -> jnp.ndarray:
+    """[P, N] one-hot of each pod's node (zero row when unbound)."""
+    return _onehot(s.assignment, a.node_mask.shape[0])
+
+
+def _pod_pairs(a: ClusterArrays, s: SchedState) -> jnp.ndarray:
+    """[P, K] pair id of each pod's node per key; 0 when unbound."""
+    bound = (s.assignment >= 0)[:, None]
+    return jnp.where(bound, a.rel.node_pair[jnp.maximum(s.assignment, 0)], 0)
+
+
+def _pick_key(per_key, keys) -> jnp.ndarray:
+    """[T, M] rows of a per-key table ([T, K, M], or [K, M] for every row)
+    at each row's key (key column 0 for a padded row, as the kernels' key
+    gathers do)."""
+    K = per_key.shape[-2]
+    sel = (jnp.maximum(keys, 0)[:, None] == jnp.arange(K))[:, :, None]  # [T, K, 1]
+    if per_key.dtype == jnp.bool_:
+        return (per_key & sel).any(axis=1)
+    return jnp.where(sel, per_key, 0).sum(axis=1, dtype=per_key.dtype)
+
+
+def _spread_counts(a: ClusterArrays, s: SchedState, mm) -> jnp.ndarray:
+    """[T, N] — counted bound pods (`mm` [T, P]) on each node."""
+    return _contract("tp,pn->tn", mm, _pod_nodes(a, s))
+
+
+def _pair_counts(a: ClusterArrays, s: SchedState, mm, keys, NP1: int, node_w=None):
+    """[T, NP1] — counted bound pods (`mm` [T, P]) by the pair their node
+    carries at row t's key (`keys` [T]; bin 0 where the node lacks it),
+    only pods on a node of `node_w` [N] when given. Counted from the pod
+    side, so every operand stays 0/1 however large the counts."""
+    if node_w is not None:
+        on_w = _contract("n,pn->p", node_w, _pod_nodes(a, s)) > 0  # [P]
+        mm = mm & on_w[None, :]
+    per_key = _contract("tp,pkv->tkv", mm, _onehot(_pod_pairs(a, s), NP1))
+    return _pick_key(per_key, keys)
+
+
+def _pair_nodes(a: ClusterArrays, keys, node_w, NP1: int) -> jnp.ndarray:
+    """[T, NP1] — nodes of `node_w` [N] carrying each pair at row t's key;
+    bin 0 (the key absent) stays 0."""
+    per_key = _contract("n,nkv->kv", node_w, _onehot(a.rel.node_pair, NP1))
+    rows = _pick_key(per_key, keys)
+    return jnp.where(jnp.arange(NP1)[None, :] > 0, rows, 0)
+
+
+def _digits(x) -> jnp.ndarray:
+    """[..., B] balanced base-256 int8 digits of the integers `x` (B their
+    byte width): Σ_j d_j·256^j == x modulo 2^(8B), every d_j in
+    [-128, 127]. A contraction of int8 digits stays exact in int32."""
+    digits = []
+    for _ in range(x.dtype.itemsize):
+        d = ((x + 128) & 255) - 128
+        digits.append(d)
+        x = (x - d) >> 8
+    return jnp.stack(digits, axis=-1).astype(jnp.int8)
+
+
+def _undigits(parts, dtype) -> jnp.ndarray:
+    """Σ_j parts[..., j]·256^j in `dtype`, wrapping as its adds do."""
+    B = parts.shape[-1]
+    scale = jnp.asarray([1 << (8 * j) for j in range(B)], dtype)
+    return (parts.astype(dtype) * scale).sum(axis=-1, dtype=dtype)
+
+
+def _term_pair_hist(contrib, pair_ot, NP1: int, weight=None) -> jnp.ndarray:
+    """[NP1] int32 — Σ over (pod, term) of `contrib` [P, T] (0/1), times
+    `weight` [P, T] when given, binned by `pair_ot` [P, T] (the pair of
+    each pod's node at the term's key). The weights ride in the mask as
+    int8 digits, so the sum is exact modulo 2^32, as an int32
+    scatter-add's is."""
+    plane = _onehot(pair_ot, NP1)  # [P, T, NP1]
+    if weight is None:
+        return _contract("pt,ptv->v", contrib, plane)
+    digits = contrib[..., None].astype(jnp.int8) * _digits(weight.astype(jnp.int32))
+    return _undigits(_contract("ptj,ptv->vj", digits, plane), jnp.int32)
+
+
+def _node_keys(a: ClusterArrays, keys) -> jnp.ndarray:
+    """[N, T] — whether each node carries row t's key (`keys` [T]; key
+    column 0 for a padded row, as the kernels' key gathers do)."""
+    return _pick_key((a.rel.node_pair > 0).T, keys).T
+
+
+def _at_node_pairs(a: ClusterArrays, table, NP1: int) -> jnp.ndarray:
+    """[..., K, N] — `table` [..., NP1] at the pair each node carries per
+    key: table[..., node_pair[n, k]]. A gather, run as contractions with
+    the nodes' one-hot pair plane; an integer table rides as int8 digits,
+    each output taking exactly one term, so the values are exact."""
+    plane = _onehot(a.rel.node_pair, NP1)  # [N, K, NP1]
+    if table.dtype == jnp.bool_:
+        return _contract("...v,nkv->...kn", table, plane) > 0
+    parts = _contract("...vj,nkv->...knj", _digits(table), plane)
+    return _undigits(parts, table.dtype)
+
+
+def _at_node_key_pairs(a: ClusterArrays, table, keys, NP1: int) -> jnp.ndarray:
+    """[N, T] — `table` [T, NP1] at the pair each node carries at row t's
+    key: table[t, node_pair[n, keys[t]]] (key column 0 for a padded row)."""
+    return _pick_key(_at_node_pairs(a, table, NP1), keys).T
+
+
+# ---------------------------------------------------------------------------
+# PodTopologySpread  (oracle: spread_pre_filter/spread_filter/
+# spread_pre_score/spread_score/spread_normalize). The oracle's
+# PreFilter/PreScore dict-building loops become the histograms above and
+# gathers back to the nodes.
+# ---------------------------------------------------------------------------
+
+
+def _spread_match(a: ClusterArrays, s: SchedState, p, ctype, ckey, cpairs):
+    """[T, P] — per constraint, the bound pods it counts (same namespace
+    as pod p, not deleted; oracle _count_matching_pods)."""
     from .encode_rel import match_clauses
 
     rel = a.rel
@@ -559,11 +692,7 @@ def _spread_counts(a: ClusterArrays, s: SchedState, p, ctype, ckey, cpairs):
         & a.pod_mask[None, :]
         & (s.assignment >= 0)[None, :]
     )
-    mm = (m & live).astype(jnp.int32)  # [T, P]
-    T = ctype.shape[0]
-    N = a.node_mask.shape[0]
-    tgt = jnp.maximum(s.assignment, 0)
-    return jnp.zeros((T, N), jnp.int32).at[:, tgt].add(mm)
+    return m & live
 
 
 def build_spread_filter(enc: EncodedCluster):
@@ -577,33 +706,27 @@ def build_spread_filter(enc: EncodedCluster):
         keys = rel.sph_key[p]  # [HC]
         HC = keys.shape[0]
         valid = keys >= 0
-        pairs = rel.node_pair[:, jnp.maximum(keys, 0)]  # [N, HC], 0 = absent
-        has_key = pairs > 0
+        has_key = _node_keys(a, keys)  # [N, HC]
         has_all = (has_key | ~valid[None, :]).all(axis=1)  # [N]
         elig = (aff_kernel(a, s, p) == 0) & has_all & a.node_mask
-        cnt_node = _spread_counts(
+        mm = _spread_match(
             a, s, p, rel.sph_ctype[p], rel.sph_ckey[p], rel.sph_cpairs[p]
-        )  # [HC, N]
-        hc_ix = jnp.arange(HC)[:, None]
-        val_cnt = jnp.zeros((HC, NP1), jnp.int32).at[hc_ix, pairs.T].add(
-            cnt_node * elig[None, :]
-        )
-        present = jnp.zeros((HC, NP1), jnp.int32).at[hc_ix, pairs.T].add(
-            (elig[:, None] & has_key).T.astype(jnp.int32)
-        )
+        )  # [HC, P]
+        val_cnt = _pair_counts(a, s, mm, keys, NP1, node_w=elig)
+        present = _pair_nodes(a, keys, elig, NP1)
         pmask = (present > 0) & (jnp.arange(NP1) > 0)[None, :]
         min_c = jnp.where(pmask, val_cnt, BIG).min(axis=1)
         min_c = jnp.where(pmask.any(axis=1), min_c, 0)  # [HC]
-        node_cnt = val_cnt[hc_ix.T, pairs]  # [N, HC]
+        node_cnt = _at_node_key_pairs(a, val_cnt, keys, NP1)  # [N, HC]
         skew = node_cnt + rel.sph_self[p][None, :].astype(jnp.int32) - min_c[None, :]
         fail_skew = skew > rel.sph_skew[p][None, :]
         code_c = jnp.where(
             ~valid[None, :], 0, jnp.where(~has_key, 1, jnp.where(fail_skew, 2, 0))
         )  # [N, HC]
-        first = jnp.argmax(code_c != 0, axis=1)
-        return jnp.where(
-            (code_c != 0).any(axis=1), code_c[jnp.arange(N), first], 0
-        ).astype(jnp.int32)
+        code = jnp.zeros((N,), jnp.int32)
+        for c in reversed(range(HC)):  # the first failing constraint's code
+            code = jnp.where(code_c[:, c] != 0, code_c[:, c], code)
+        return code
 
     return kernel
 
@@ -641,18 +764,14 @@ def build_spread_score(enc: EncodedCluster):
         rel = a.rel
         keys = rel.sps_key[p]
         valid = keys >= 0
-        pairs = rel.node_pair[:, jnp.maximum(keys, 0)]
-        has_key = pairs > 0
+        has_key = _node_keys(a, keys)  # [N, SC]
         has_all = (has_key | ~valid[None, :]).all(axis=1)
         ignored = feasible & rel.req_all[p] & ~has_all
-        return keys, valid, pairs, has_key, has_all, ignored
+        return keys, valid, has_key, has_all, ignored
 
     def kernel(a: ClusterArrays, s: SchedState, p, feasible) -> jnp.ndarray:
         rel = a.rel
-        keys, valid, pairs, has_key, has_all, ignored = soft_ignored(
-            a, s, p, feasible
-        )
-        SC = keys.shape[0]
+        keys, valid, has_key, has_all, ignored = soft_ignored(a, s, p, feasible)
         scored = feasible & ~ignored
         n_scored = scored.sum().astype(jnp.int32)
         count_mask = (
@@ -660,22 +779,18 @@ def build_spread_score(enc: EncodedCluster):
             & jnp.where(rel.req_all[p], has_all, True)
             & a.node_mask
         )
-        cnt_node = _spread_counts(
+        mm = _spread_match(
             a, s, p, rel.sps_ctype[p], rel.sps_ckey[p], rel.sps_cpairs[p]
-        )  # [SC, N]
-        sc_ix = jnp.arange(SC)[:, None]
-        val_cnt = jnp.zeros((SC, NP1), jnp.int32).at[sc_ix, pairs.T].add(
-            cnt_node * count_mask[None, :]
-        )
-        present = jnp.zeros((SC, NP1), jnp.int32).at[sc_ix, pairs.T].add(
-            (scored[:, None] & has_key).T.astype(jnp.int32)
-        )
+        )  # [SC, P]
+        cnt_node = _spread_counts(a, s, mm)  # [SC, N]
+        val_cnt = _pair_counts(a, s, mm, keys, NP1, node_w=count_mask)
+        present = _pair_nodes(a, keys, scored, NP1)
         topo_size = ((present > 0) & (jnp.arange(NP1) > 0)[None, :]).sum(axis=1)
         host = rel.sps_host[p]  # [SC]
         w_m = jnp.where(host, n_scored, topo_size)
         w_q = rel.spread_lut[jnp.clip(w_m, 0, rel.spread_lut.shape[0] - 1)]  # [SC]
-        node_cnt = val_cnt[sc_ix.T, pairs]  # [N, SC]
-        val_ok = present[sc_ix.T, pairs] > 0
+        node_cnt = _at_node_key_pairs(a, val_cnt, keys, NP1)  # [N, SC]
+        val_ok = _at_node_key_pairs(a, present > 0, keys, NP1)
         cnt = jnp.where(host[None, :], cnt_node.T, node_cnt)
         apply = valid[None, :] & has_key & (host[None, :] | val_ok)
         totq = (jnp.where(apply, cnt, 0) * w_q[None, :]).sum(axis=1)
@@ -718,100 +833,76 @@ TRIVIAL_PRESCORE.add("PodTopologySpread")
 # directions run on-device: the incoming pod's terms vs every pod
 # (match_clauses) and every pod's terms vs the incoming pod
 # (match_clauses_rev); topology localization reduces through the node
-# (key,value)-pair vocab with scatter-adds keyed on state.assignment.
+# (key,value)-pair vocab with the topology histograms above.
 # ---------------------------------------------------------------------------
 
 
-def _ipa_forward_live(a: ClusterArrays, s: SchedState, p, nsall, nsmh):
-    """[T, P] liveness+namespace mask for the incoming pod's terms against
-    every candidate target pod (bound, real, in the term's namespaces)."""
-    rel = a.rel
-    bound = (s.assignment >= 0) & a.pod_mask
-    ns_ok = nsall[p][:, None] | nsmh[p][:, rel.ns_id]  # [T, P]
-    return ns_ok & bound[None, :]
-
-
-def _pair_of_assigned(a: ClusterArrays, s: SchedState, key_cols):
-    """[..., P]→ for each pod, the node-pair id of its assigned node at the
-    given key columns. key_cols [T] → returns [T, P]; 0 where unbound or
-    key absent on the node."""
-    rel = a.rel
-    np_assigned = rel.node_pair[jnp.maximum(s.assignment, 0)]  # [P, K]
-    pair = np_assigned[:, jnp.maximum(key_cols, 0)].T  # [T, P]
-    ok = (key_cols >= 0)[:, None] & (s.assignment >= 0)[None, :]
-    return jnp.where(ok, pair, 0)
-
-
-def _forward_match(a, s, p, key_cols, ctype, ckey, cpairs, nsall, nsmh):
-    """(m [T, P], pair_tp [T, P]) — per incoming term: which bound pods
-    match, and the (topologyKey, value) pair id of each pod's node."""
+def _ipa_forward_match(a: ClusterArrays, s: SchedState, p, ctype, ckey, cpairs, nsall, nsmh):
+    """[T, P] — per incoming term, the bound pods it matches (real, in the
+    term's namespaces). A padded term slot matches nothing: its namespace
+    masks are empty."""
     from .encode_rel import match_clauses
 
-    m = match_clauses(a.rel, ctype[p], ckey[p], cpairs[p])  # [T, P]
-    m = m & _ipa_forward_live(a, s, p, nsall, nsmh)
-    pair_tp = _pair_of_assigned(a, s, key_cols[p])  # [T, P]
-    return m, pair_tp
+    rel = a.rel
+    m = match_clauses(rel, ctype[p], ckey[p], cpairs[p])  # [T, P]
+    bound = (s.assignment >= 0) & a.pod_mask
+    ns_ok = nsall[p][:, None] | nsmh[p][:, rel.ns_id]  # [T, P]
+    return m & ns_ok & bound[None, :]
 
 
 def _forward_pair_counts(a, s, p, key_cols, ctype, ckey, cpairs, nsall, nsmh, NP1):
     """[T, NP1] — per incoming term, matching bound pods grouped by the
     (topologyKey, value) pair of their node."""
-    m, pair_tp = _forward_match(a, s, p, key_cols, ctype, ckey, cpairs, nsall, nsmh)
-    T = pair_tp.shape[0]
-    return (
-        jnp.zeros((T, NP1), jnp.int32)
-        .at[jnp.arange(T)[:, None], pair_tp]
-        .add(m.astype(jnp.int32))
-    )
+    m = _ipa_forward_match(a, s, p, ctype, ckey, cpairs, nsall, nsmh)
+    return _pair_counts(a, s, m, key_cols[p], NP1)
+
+
+def _reverse_terms(a: ClusterArrays, s: SchedState, p, key, ctype, ckey, cpairs, nsall, nsmh):
+    """(contrib [P, T], pair_ot [P, T]) — which existing pods' terms match
+    the incoming pod p and count (bound, key present on their node), and
+    the pair of each pod's node at its term's key (pod-independent)."""
+    from .encode_rel import match_clauses_rev
+
+    rel = a.rel
+    rev = match_clauses_rev(rel, ctype, ckey, cpairs, p)  # [P, T]
+    ns_ok = nsall | nsmh[:, :, rel.ns_id[p]]
+    pair_ot = jnp.take_along_axis(_pod_pairs(a, s), jnp.maximum(key, 0), axis=1)
+    bound = (s.assignment >= 0) & a.pod_mask
+    contrib = rev & ns_ok & (key >= 0) & bound[:, None] & (pair_ot > 0)
+    return contrib, pair_ot
 
 
 def build_interpod_filter(enc: EncodedCluster):
-    from .encode_rel import match_clauses_rev
-
     NP1 = enc.aux["n_node_pairs"] + 1
 
     def kernel(a: ClusterArrays, s: SchedState, p) -> jnp.ndarray:
         rel = a.rel
-        bound = (s.assignment >= 0) & a.pod_mask
         # (1) existing pods' required anti-affinity vs the incoming pod
-        rev = match_clauses_rev(rel, rel.ian_ctype, rel.ian_ckey, rel.ian_cpairs, p)
-        ns_ok = rel.ian_nsall | rel.ian_ns[:, :, rel.ns_id[p]]  # [P, T]
-        np_assigned = rel.node_pair[jnp.maximum(s.assignment, 0)]  # [P, K]
-        pair_ot = jnp.take_along_axis(
-            np_assigned, jnp.maximum(rel.ian_key, 0), axis=1
-        )  # [P, T]
-        contrib = (
-            rev
-            & ns_ok
-            & (rel.ian_key >= 0)
-            & bound[:, None]
-            & (pair_ot > 0)
+        contrib, pair_ot = _reverse_terms(
+            a, s, p, rel.ian_key, rel.ian_ctype, rel.ian_ckey, rel.ian_cpairs,
+            rel.ian_nsall, rel.ian_ns,
         )
-        ea_cnt = jnp.zeros(NP1, jnp.int32).at[pair_ot].add(contrib.astype(jnp.int32))
-        ea_node = ea_cnt[rel.node_pair]  # [N, K]
-        fail1 = ((ea_node > 0) & (rel.node_pair > 0)).any(axis=1)
+        ea_cnt = _term_pair_hist(contrib, pair_ot, NP1)  # [NP1]
+        ea_node = _at_node_pairs(a, ea_cnt > 0, NP1).T  # [N, K]
+        fail1 = (ea_node & (rel.node_pair > 0)).any(axis=1)
         # (2) incoming pod's required anti-affinity
         anti_cnt = _forward_pair_counts(
             a, s, p, rel.ian_key, rel.ian_ctype, rel.ian_ckey, rel.ian_cpairs,
             rel.ian_nsall, rel.ian_ns, NP1,
         )  # [T, NP1]
         key2 = rel.ian_key[p]  # [T]
-        T2 = key2.shape[0]
-        npair2 = rel.node_pair[:, jnp.maximum(key2, 0)]  # [N, T]
-        cnt2 = anti_cnt[jnp.arange(T2)[None, :], npair2]  # [N, T]
-        fail2 = ((npair2 > 0) & (cnt2 > 0) & (key2 >= 0)[None, :]).any(axis=1)
+        hit2 = _at_node_key_pairs(a, anti_cnt > 0, key2, NP1)  # [N, T]
+        fail2 = (_node_keys(a, key2) & hit2 & (key2 >= 0)[None, :]).any(axis=1)
         # (3) incoming pod's required affinity
         aff_cnt = _forward_pair_counts(
             a, s, p, rel.ia_key, rel.ia_ctype, rel.ia_ckey, rel.ia_cpairs,
             rel.ia_nsall, rel.ia_ns, NP1,
         )
         key3 = rel.ia_key[p]
-        T3 = key3.shape[0]
         tvalid3 = key3 >= 0
         has_terms = tvalid3.any()
-        npair3 = rel.node_pair[:, jnp.maximum(key3, 0)]
-        cnt3 = aff_cnt[jnp.arange(T3)[None, :], npair3]
-        ok_t = (npair3 > 0) & (cnt3 > 0)
+        has3 = _node_keys(a, key3)  # [N, T]
+        ok_t = has3 & _at_node_key_pairs(a, aff_cnt > 0, key3, NP1)
         satisfied = (ok_t | ~tvalid3[None, :]).all(axis=1)
         # first-pod-in-series: no term matched anything anywhere AND the
         # pod matches all of its own terms (oracle interpod_filter) — gated
@@ -819,7 +910,7 @@ def build_interpod_filter(enc: EncodedCluster):
         # satisfyPodAffinity fails such nodes before the special case)
         total_matches = aff_cnt[:, 1:].sum()
         self_all = (rel.ia_self[p] | ~tvalid3).all()
-        has_all_keys = ((npair3 > 0) | ~tvalid3[None, :]).all(axis=1)  # [N]
+        has_all_keys = (has3 | ~tvalid3[None, :]).all(axis=1)  # [N]
         pass3 = satisfied | (has_all_keys & (total_matches == 0) & self_all)
         fail3 = has_terms & ~pass3
         return jnp.where(
@@ -840,8 +931,6 @@ def decode_interpod(code: int, enc: EncodedCluster, node_idx: int) -> str:
 def build_interpod_score(enc: EncodedCluster):
     """topology_score[(key,val)] accumulated into a node-pair weight array,
     gathered per node (oracle interpod_pre_score/interpod_score)."""
-    from .encode_rel import match_clauses_rev
-
     if "InterPodAffinity" not in enc.config.enabled("preScore"):
 
         def zero_kernel(a, s, p, feasible):
@@ -858,7 +947,6 @@ def build_interpod_score(enc: EncodedCluster):
 
     def kernel(a: ClusterArrays, s: SchedState, p, feasible) -> jnp.ndarray:
         rel = a.rel
-        bound = (s.assignment >= 0) & a.pod_mask
         wsum = jnp.zeros(NP1, score_dt)
         # incoming pod's preferred terms vs existing pods (weight ±w)
         for key, ct, ck, cp, na, nm, w, sign in (
@@ -867,9 +955,9 @@ def build_interpod_score(enc: EncodedCluster):
             (rel.ipan_key, rel.ipan_ctype, rel.ipan_ckey, rel.ipan_cpairs,
              rel.ipan_nsall, rel.ipan_ns, rel.ipan_weight, -1),
         ):
-            m, pair_tp = _forward_match(a, s, p, key, ct, ck, cp, na, nm)
+            cnt = _forward_pair_counts(a, s, p, key, ct, ck, cp, na, nm, NP1)
             wt = (sign * w[p]).astype(score_dt)[:, None]  # [T, 1]
-            wsum = wsum.at[pair_tp].add(jnp.where(m, wt, 0))
+            wsum = wsum + (cnt.astype(score_dt) * wt).sum(axis=0, dtype=score_dt)
         # existing pods' terms vs the incoming pod: preferred ±w, and
         # required affinity at hardPodAffinityWeight
         rev_domains = [
@@ -884,20 +972,14 @@ def build_interpod_score(enc: EncodedCluster):
                  rel.ia_nsall, rel.ia_ns, None, hard_w)
             )
         for key, ct, ck, cp, na, nm, w, sign in rev_domains:
-            rev = match_clauses_rev(rel, ct, ck, cp, p)  # [P, T]
-            ns_ok = na | nm[:, :, rel.ns_id[p]]
-            pair_ot = jnp.take_along_axis(
-                rel.node_pair[jnp.maximum(s.assignment, 0)],
-                jnp.maximum(key, 0),
-                axis=1,
-            )  # [P, T]
-            contrib = rev & ns_ok & (key >= 0) & bound[:, None] & (pair_ot > 0)
-            wt = (sign * w).astype(score_dt) if w is not None else jnp.full(
-                key.shape, sign, score_dt
-            )
-            wsum = wsum.at[pair_ot].add(jnp.where(contrib, wt, 0))
-        vals = jnp.where(rel.node_pair > 0, wsum[rel.node_pair], 0)  # [N, K]
-        return vals.sum(axis=1).astype(score_dt)
+            contrib, pair_ot = _reverse_terms(a, s, p, key, ct, ck, cp, na, nm)
+            if w is None:
+                hist = sign * _term_pair_hist(contrib, pair_ot, NP1)
+            else:
+                hist = _term_pair_hist(contrib, pair_ot, NP1, weight=sign * w)
+            wsum = wsum + hist.astype(score_dt)
+        wsum = jnp.where(jnp.arange(NP1) > 0, wsum, 0)  # a key the node lacks adds 0
+        return _at_node_pairs(a, wsum, NP1).sum(axis=0, dtype=score_dt)
 
     def normalize(a, s, p, raw, feasible):
         BIG = jnp.iinfo(jnp.int32).max
