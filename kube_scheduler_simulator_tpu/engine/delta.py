@@ -326,6 +326,13 @@ class DeltaEncoder:
     returned encoding as dead once `encode` returns a newer one — the
     serving layer's engine caches do (they `retarget` onto the new
     encoding before running).
+
+    The pod capacity has hysteresis: a full re-encode whose live pod
+    count fell below the last pod bucket keeps that bucket unless the
+    count fits the next bucket down within `utils/broker.BUCKET_WATERMARK`
+    of it. A store whose count swings across a power of
+    two (pods deleted, then as many created) then keeps one compiled
+    program instead of recompiling at each crossing.
     """
 
     def __init__(
@@ -340,6 +347,7 @@ class DeltaEncoder:
         self.node_lo = node_lo
         self.pod_lo = pod_lo
         self.max_dirty_frac = max_dirty_frac
+        self._pod_bucket = 0  # the last full encode's pod capacity
         self._st: "_Retained | None" = None
         # host->device bytes the LAST encode() shipped: the full encoded
         # cluster on a full pass, the dirty row stacks on a delta pass,
@@ -394,6 +402,10 @@ class DeltaEncoder:
         ncap, pcap = capacity_buckets(
             len(nodes), len(pods), node_lo=self.node_lo, pod_lo=self.pod_lo
         )
+        last = self._pod_bucket
+        if pcap < last and len(pods) > broker_mod.BUCKET_WATERMARK * (last // 2):
+            pcap = last
+        self._pod_bucket = pcap
         enc = encode_cluster(
             nodes,
             pods,

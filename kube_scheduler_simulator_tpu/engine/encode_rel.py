@@ -26,6 +26,7 @@ import numpy as np
 
 from ..models.vocab import Vocab
 from ..sched.oracle_plugins import spread_log_weight
+from ..utils.compilecache import shape_bucket
 from .packing import put_field
 
 PAIR_ANY, NOTIN, EXISTS, DNE, NEVER = 0, 1, 2, 3, 4
@@ -421,7 +422,9 @@ def encode_pod_relations(
     hk, hs, hself, _, hct, hck, hcp = pack(hard_all)
     sk, ss_, _, shost, sct, sck, scp = pack(soft_all)
 
-    NSV = max(1, len(ns_vocab))
+    # the namespace axis is bucketed like the pod and node axes: a store
+    # whose pods leave and re-enter a namespace keeps one program
+    NSV = max(1, shape_bucket(len(ns_vocab), lo=8))
 
     def pack_terms(parsed):
         T = max(1, max((len(x) for x in parsed), default=0))

@@ -36,6 +36,7 @@ from ..utils import broker as broker_mod
 from . import kernels as K
 from .encode import EncodedCluster
 from .packing import make_unpacker
+from .preempt import nominated_victims
 
 class UnsupportedPluginError(NotImplementedError):
     pass
@@ -46,31 +47,39 @@ class UnsupportedPluginError(NotImplementedError):
 # unpacking; step() emits exactly these, in this order.
 TRACE_SLOTS_PREEMPT = (
     "pf_codes", "codes", "raw", "final", "sel", "did",
-    "pcode", "vmask", "nominated",
-    "codes2", "raw2", "final2", "sel2", "pcode2", "vmask2", "nominated2",
+    "pcode", "victims", "nominated",
+    "codes2", "raw2", "final2", "sel2", "pcode2", "victims2", "nominated2",
     "final_sel",
 )
 TRACE_SLOTS_PLAIN = ("pf_codes", "codes", "raw", "final", "sel")
-# Slots run_chunked keeps sparsely (fired rows only): the [N, P] victim
-# masks plus every retry-attempt tensor — results() reads all of them
+# Slots run_chunked keeps sparsely (fired rows only): the [N, V] victim
+# rows plus every retry-attempt tensor — results() reads all of them
 # only under did[qi], so event-free pods need no storage or transfer.
 TRACE_SPARSE_SLOTS = frozenset(
     TRACE_SLOTS_PREEMPT.index(n)
-    for n in ("vmask", "vmask2", "pcode", "codes2", "raw2", "final2", "pcode2")
+    for n in ("victims", "victims2", "pcode", "codes2", "raw2", "final2", "pcode2")
 )
 TRACE_DID_SLOT = TRACE_SLOTS_PREEMPT.index("did")
+
+
+def victims_by_node_index(victims_row, n_nodes: int) -> "dict[int, list[int]]":
+    """Per node, the victim pod indices of a dry run's `[N, V]` rows, in
+    the rows' (reprieve) order, -1 padding dropped."""
+    rows = np.asarray(victims_row)
+    return {n: [int(v) for v in rows[n] if v >= 0] for n in range(n_nodes)}
 
 
 class _SparseRows:
     """Row-indexable stand-in for a stacked [P, ...] trace tensor that
     materializes only the rows recorded by `run_chunked` (pods whose
-    preemption dry-run fired); other rows read as zeros."""
+    preemption dry-run fired); other rows read as `fill` (zeros; -1,
+    no victim, for the victim rows)."""
 
     def __init__(
-        self, rows: "dict[int, np.ndarray]", row_shape: tuple, dtype=bool
+        self, rows: "dict[int, np.ndarray]", row_shape: tuple, dtype=bool, fill=0
     ):
         self._rows = rows
-        self._zero = np.zeros(row_shape, dtype)
+        self._zero = np.full(row_shape, fill, dtype)
         self._zero.setflags(write=False)  # shared across misses
 
     def __getitem__(self, qi: int) -> np.ndarray:
@@ -580,45 +589,46 @@ class BatchedScheduler:
                 # — so binding proceeds from `sel` exactly as the skipped
                 # branch would. Retry outputs are zero-gated to match the
                 # cond mode's `without` trace bit-for-bit.
-                pcode, vmask, nominated = preempt_fn(a, st, ps)
+                pcode, victims, nominated = preempt_fn(a, st, ps)
                 nominated = jnp.where(do, nominated, jnp.int32(-1))
-                vmask = vmask & do
+                victims = jnp.where(do, victims, -1)
                 pcode = jnp.where(do, pcode, 0)
-                evict = vmask[jnp.maximum(nominated, 0)] & (nominated >= 0)
+                evict = nominated_victims(victims, nominated, P)
                 st2 = evict_all(st, a, evict)
                 _, codes2, raw2, final2, sel2, _ = attempt(st2, a, weights, ps)
-                pcode2, vmask2, nominated2 = preempt_fn(a, st2, ps)
+                pcode2, victims2, nominated2 = preempt_fn(a, st2, ps)
                 return st2, (
-                    pcode, vmask, nominated, evict,
+                    pcode, victims, nominated, evict,
                     jnp.where(do, codes2, 0),
                     jnp.where(do, raw2, 0),
                     jnp.where(do, final2, 0),
                     jnp.where(do, sel2, jnp.int32(-1)),
                     jnp.where(do, pcode2, 0),
-                    vmask2 & do,
+                    jnp.where(do, victims2, -1),
                     jnp.where(do, nominated2, jnp.int32(-1)),
                 )
 
             def with_preempt(st):
-                pcode, vmask, nominated = preempt_fn(a, st, ps)
-                evict = vmask[jnp.maximum(nominated, 0)] & (nominated >= 0)
+                pcode, victims, nominated = preempt_fn(a, st, ps)
+                evict = nominated_victims(victims, nominated, P)
                 st2 = evict_all(st, a, evict)
                 _, codes2, raw2, final2, sel2, _ = attempt(st2, a, weights, ps)
                 # retry-failure postfilter (recorded, never evicts — the
                 # oracle's retried-set forces Unschedulable on 2nd failure)
-                pcode2, vmask2, nominated2 = preempt_fn(a, st2, ps)
+                pcode2, victims2, nominated2 = preempt_fn(a, st2, ps)
                 return st2, (
-                    pcode, vmask, nominated, evict,
-                    codes2, raw2, final2, sel2, pcode2, vmask2, nominated2,
+                    pcode, victims, nominated, evict,
+                    codes2, raw2, final2, sel2, pcode2, victims2, nominated2,
                 )
 
             def without(st):
+                V = preempt_fn.victim_slots
                 return st, (
-                    jnp.zeros(N, jnp.int32), jnp.zeros((N, P), bool),
+                    jnp.zeros(N, jnp.int32), jnp.full((N, V), -1, jnp.int32),
                     jnp.int32(-1), jnp.zeros(P, bool),
                     jnp.zeros_like(codes), jnp.zeros_like(raw),
                     jnp.zeros_like(final), jnp.int32(-1),
-                    jnp.zeros(N, jnp.int32), jnp.zeros((N, P), bool),
+                    jnp.zeros(N, jnp.int32), jnp.full((N, V), -1, jnp.int32),
                     jnp.int32(-1),
                 )
 
@@ -626,15 +636,15 @@ class BatchedScheduler:
                 state, extra = masked_preempt(state)
             else:
                 state, extra = jax.lax.cond(do, with_preempt, without, state)
-            (pcode, vmask, nominated, evict,
-             codes2, raw2, final2, sel2, pcode2, vmask2, nominated2) = extra
+            (pcode, victims, nominated, evict,
+             codes2, raw2, final2, sel2, pcode2, victims2, nominated2) = extra
             final_sel = jnp.where(do & (nominated >= 0), sel2, sel)
             state = bind(state, a, p, final_sel, qi)
             if record:
                 out = (
                     pf_codes, codes, raw, final, sel, do,
-                    pcode, vmask, nominated,
-                    codes2, raw2, final2, sel2, pcode2, vmask2, nominated2,
+                    pcode, victims, nominated,
+                    codes2, raw2, final2, sel2, pcode2, victims2, nominated2,
                     final_sel,
                 )
             else:
@@ -708,13 +718,12 @@ class BatchedScheduler:
         trace to host memory — the at-scale `record=True` strategy.
 
         The full-run trace is O(P) stacked per-step tensors; with
-        preemption enabled the dominant term is two [N, P] victim masks
-        per pod (~2e11 bools at 10k pods x 1k nodes), which cannot live
-        on device. Chunking bounds device trace memory to
-        `chunk x per-step-trace`; on the host the victim masks are kept
-        sparsely — only the rows of pods whose preemption dry-run
-        actually fired (`did`) — so host memory scales with the number
-        of preemption events, not P x N x P. `results()` then decodes
+        preemption enabled the dominant terms are the per-node retry
+        attempt and the two [N, V] victim rows of every pod. Chunking
+        bounds device trace memory to `chunk x per-step-trace`; on the
+        host those slots are kept sparsely — only the rows of pods whose
+        preemption dry-run actually fired (`did`) — so host memory
+        scales with the number of preemption events, not P x N. `results()` then decodes
         (optionally a subset of pods; see `results(pods=...)`).
 
         The trailing partial chunk is padded to the full chunk length
@@ -747,7 +756,7 @@ class BatchedScheduler:
             out = list(out) if isinstance(out, (tuple, list)) else [out]
             # fired-row indices first: event-free chunks transfer nothing
             # from the big per-attempt slots, and the sparse-slot gathers
-            # keep host memory proportional to fired rows, not P x N x P
+            # keep host memory proportional to fired rows, not P x N
             fired = (
                 np.nonzero(np.asarray(out[TRACE_DID_SLOT]))[0] if has_pf else ()
             )
@@ -774,7 +783,8 @@ class BatchedScheduler:
         for j in range(n_slots):
             if j in sparse_slots:
                 shape, dtype = zero_spec[j]
-                trace.append(_SparseRows(sparse[j], shape, dtype))
+                fill = -1 if TRACE_SLOTS_PREEMPT[j].startswith("victims") else 0
+                trace.append(_SparseRows(sparse[j], shape, dtype, fill))
             else:
                 trace.append(np.concatenate(dense[j], axis=0))
         self._final_state = state
@@ -831,28 +841,14 @@ class BatchedScheduler:
         record_bind_points(enc.config, res, permit=permit)
         return True
 
-    def _ordered_victims(self, vmask_row, seq) -> "dict[int, list[int]]":
-        """Per candidate node, the victim pod INDICES in reprieve
-        processing order: priority desc, bind order asc (oracle
-        NodeInfo.pods insertion order for ties). Shared by the trace
-        decode below and the extender loop's preemption path — one
-        definition of the order the records promise."""
-        enc = self.enc
-        prio = np.asarray(enc.arrays.pod_priority)
-        out = {}
-        for n in range(enc.n_nodes):
-            vs = [int(v) for v in np.nonzero(vmask_row[n])[0]]
-            vs.sort(key=lambda v: (-int(prio[v]), int(seq[v])))
-            out[n] = vs
-        return out
-
-    def _fill_postfilter(self, res, pcode_row, vmask_row, seq, victims=None):
+    def _fill_postfilter(self, res, pcode_row, victims_row):
         """Attach DefaultPreemption messages (oracle default_preemption's
-        per-node messages dict). Returns (nominated victims by node).
-        `victims`: optional precomputed `_ordered_victims` output."""
+        per-node messages dict). `victims_row`: the dry run's `[N, V]`
+        victim pod indices, each node's already in reprieve order
+        (priority desc, bind order asc), -1-padded. Returns the victims'
+        names by node."""
         enc = self.enc
-        if victims is None:
-            victims = self._ordered_victims(vmask_row, seq)
+        victims = victims_by_node_index(victims_row, enc.n_nodes)
         victims_by_node = {}
         for n in range(enc.n_nodes):
             code = int(pcode_row[n])
@@ -899,25 +895,16 @@ class BatchedScheduler:
             for i, v in zip(dev_idx, fetched):
                 vals[i] = np.asarray(v)
         if has_pf:
-            (pf_codes, codes, raw, final, sel, did, pcode, vmask, nominated,
-             codes2, raw2, final2, sel2, pcode2, vmask2, nominated2,
+            (pf_codes, codes, raw, final, sel, did, pcode, victims, nominated,
+             codes2, raw2, final2, sel2, pcode2, victims2, nominated2,
              final_sel) = vals
         else:
             pf_codes, codes, raw, final, sel = vals
             final_sel = sel
         results = []
-        # bind chronology for victim-ordering (mirrors state.bound_seq)
-        seq = np.asarray(enc.state0.bound_seq).copy()
         for qi, p in enumerate(enc.queue):
             ns, name = enc.pod_keys[p]
             if pods is not None and (ns, name) not in pods:
-                # bind-chronology bookkeeping must still advance so later
-                # decoded pods order their victim lists correctly
-                if int(final_sel[qi]) >= 0:
-                    seq[p] = enc.P + qi
-                if has_pf and bool(did[qi]) and int(nominated[qi]) >= 0:
-                    for v in np.nonzero(vmask[qi][int(nominated[qi])])[0]:
-                        seq[int(v)] = -1
                 continue
             res = PodSchedulingResult(pod_namespace=ns, pod_name=name)
             pf_failed = False
@@ -937,9 +924,7 @@ class BatchedScheduler:
                 continue
             self._fill_attempt(res, codes[qi], raw[qi], final[qi], sel[qi], p)
             if has_pf and bool(did[qi]):
-                victims_by_node = self._fill_postfilter(
-                    res, pcode[qi], vmask[qi], seq
-                )
+                victims_by_node = self._fill_postfilter(res, pcode[qi], victims[qi])
                 nom = int(nominated[qi])
                 if nom >= 0:
                     res.status = "Nominated"
@@ -954,7 +939,7 @@ class BatchedScheduler:
                         res2, codes2[qi], raw2[qi], final2[qi], sel2[qi], p
                     )
                     if not ok:
-                        self._fill_postfilter(res2, pcode2[qi], vmask2[qi], seq)
+                        self._fill_postfilter(res2, pcode2[qi], victims2[qi])
                         nom2 = int(nominated2[qi])
                         if nom2 >= 0:
                             res2.nominated_node = enc.node_names[nom2]
@@ -965,9 +950,4 @@ class BatchedScheduler:
                     results.append(res)
             else:
                 results.append(res)
-            if int(final_sel[qi]) >= 0:
-                seq[p] = enc.P + qi
-            if has_pf and bool(did[qi]) and int(nominated[qi]) >= 0:
-                for v in np.nonzero(vmask[qi][int(nominated[qi])])[0]:
-                    seq[int(v)] = -1
         return results

@@ -41,7 +41,7 @@ from ..sched.results import (
 from ..sched.extender import ExtenderError, ExtenderService
 from ..utils import broker as broker_mod
 from . import kernels as K
-from .engine import BatchedScheduler
+from .engine import BatchedScheduler, victims_by_node_index
 from .encode import EncodedCluster
 
 
@@ -208,14 +208,13 @@ class ExtenderScheduler:
         codes)."""
         import jax.numpy as jnp
 
-        pcode, vmask, nominated = self.preempt_fn(
+        pcode, slots, nominated = self.preempt_fn(
             self.enc.arrays, state, jnp.int32(p)
         )
         pcode = np.asarray(pcode)
-        vmask = np.asarray(vmask)
-        seq = np.asarray(state.bound_seq)
-        victims = self.sched._ordered_victims(vmask, seq)
-        self.sched._fill_postfilter(res, pcode, vmask, seq, victims=victims)
+        slots = np.asarray(slots)
+        self.sched._fill_postfilter(res, pcode, slots)
+        victims = victims_by_node_index(slots, self.enc.n_nodes)
         return int(np.asarray(nominated)), victims, pcode
 
     def _victim_uid(self, v: int) -> str:

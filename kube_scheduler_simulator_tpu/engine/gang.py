@@ -95,6 +95,7 @@ from . import kernels as K
 from .encode import EncodedCluster
 from .engine import BatchedScheduler
 from .packing import make_unpacker
+from .preempt import nominated_victims
 
 # queue-position value that can never win a scatter-min
 _NO_ORDER = jnp.iinfo(jnp.int32).max
@@ -423,6 +424,7 @@ class GangScheduler:
         )
         self._final_state = None
         self._rounds = None
+        self._preempt_counts = None
         # record path (results()) — all built/filled lazily so the
         # default fixpoint program and its compile class stay untouched
         self._run_tracked = None
@@ -665,24 +667,30 @@ class GangScheduler:
 
         def preempt_phase(arrays, state, seg, order, weights):
             """Sequential preempt pass over the pods the round loop left
-            pending. `seg`: [K] pod indices in queue (PrioritySort) order,
-            -1-padded. Per pod: full attempt → masked preemption dry-run →
-            evict victims → retry → bind (the sequential engine's step
-            semantics, reference wrappedplugin.go:518-546), expressed with
-            the gang module's mask-vector bind so padded rows are exact
-            no-ops. Returns (state, pods bound this phase)."""
+            pending. `seg`: pod indices in queue (PrioritySort) order,
+            then -1 padding. Per pod: full attempt → masked preemption
+            dry-run → evict victims → retry → bind (the sequential
+            engine's step semantics, reference wrappedplugin.go:518-546),
+            expressed with the gang module's mask-vector bind. The loop
+            runs one step per pod of `seg` and none for its padding, so a
+            phase costs its pending pods, whatever the segment's length
+            (under vmap it runs to the batch's longest segment, where the
+            shorter ones' padded rows are exact no-ops). Returns (state,
+            counts): int32[3] of steps run, pods bound, pods evicted."""
             a = unpack(arrays)
+            n_pend = (seg >= 0).sum(dtype=jnp.int32)
 
-            def pstep(state, p_raw):
+            def pstep(carry):
+                state, i, n_bound, n_evicted = carry
+                p_raw = seg[i]
                 valid = p_raw >= 0
                 p = jnp.maximum(p_raw, 0)
                 _, _, _, _, sel, pf_ok = attempt(state, a, weights, p)
                 pending = valid & (state.assignment[p] < 0) & a.pod_mask[p]
                 do = pending & (sel < 0) & pf_ok
-                pcode, vmask, nominated = preempt_fn(a, state, p)
+                _, victims, nominated = preempt_fn(a, state, p)
                 nominated = jnp.where(do, nominated, jnp.int32(-1))
-                vmask = vmask & do
-                evict = vmask[jnp.maximum(nominated, 0)] & (nominated >= 0)
+                evict = nominated_victims(victims, nominated, P)
                 state = evict_all(state, a, evict)
                 _, _, _, _, sel2, _ = attempt(state, a, weights, p)
                 # an earlier eviction in this phase may have made the pod
@@ -697,10 +705,20 @@ class GangScheduler:
                 mask_vec = jnp.zeros((P,), bool).at[p].set(commit)
                 sel_vec = jnp.full((P,), -1, jnp.int32).at[p].set(final_sel)
                 state = bind_all(state, a, mask_vec, sel_vec, order)
-                return state, commit
+                return (
+                    state,
+                    i + 1,
+                    n_bound + commit.astype(jnp.int32),
+                    n_evicted + evict.sum(dtype=jnp.int32),
+                )
 
-            state, commits = jax.lax.scan(pstep, state, seg)
-            return state, commits.sum().astype(jnp.int32)
+            # the carried step count is what the phase ran: the counter
+            # reads the loop, not the segment
+            zero = jnp.int32(0)
+            state, n_steps, n_bound, n_evicted = jax.lax.while_loop(
+                lambda carry: carry[1] < n_pend, pstep, (state, zero, zero, zero)
+            )
+            return state, jnp.stack([n_steps, n_bound, n_evicted])
 
         self.preempt_phase_fn = preempt_phase if preempt_fn is not None else None
         # building blocks for the record path (results()): advance a
@@ -1224,12 +1242,16 @@ class GangScheduler:
 
             The phase segment is built on device: a stable argsort of
             `order` masked to pending pods (identical to the host's
-            `pending[np.argsort(order[pending])]`), -1-padded to fixed
-            length P — pstep rows with p_raw == -1 are exact no-ops, so
-            the fixed-length scan replaces the host path's
-            pow2-padded-segment recompile family with ONE phase shape.
-            Each resume re-enters `run`'s loop with a fresh max_rounds
-            commit budget, matching the host driver's per-call budget.
+            `pending[np.argsort(order[pending])]`), -1 past the `n_pend`
+            pending pods — ONE phase shape, in place of the host path's
+            pow2-padded-segment recompile family, and the phase loop
+            steps `n_pend` times. Each resume re-enters `run`'s loop with
+            a fresh max_rounds commit budget, matching the host driver's
+            per-call budget.
+
+            Returns (state, (rounds, preempt)): `preempt` int32[3] sums
+            the phases' (steps, pods bound, pods evicted) — the finish
+            fetches both with the assignment.
 
             Caveats: under vmap (batch.gang.run) the two `lax.cond`
             guards lower to both-branches-plus-select, so converged
@@ -1243,12 +1265,13 @@ class GangScheduler:
             # nested run/preempt_phase unpacks become static no-ops
             arrays = unpack(arrays)
             state, rounds = run(arrays, state0, order, weights)
+            no_phase = jnp.zeros(3, jnp.int32)
             if preempt_fn is None:
-                return state, rounds
+                return state, (rounds, no_phase)
             in_queue = order != _NO_ORDER
 
             def obody(carry):
-                state, rounds, _ = carry
+                state, rounds, counts, _ = carry
                 pending = (
                     (state.assignment < 0) & in_queue & arrays.pod_mask
                 )
@@ -1261,26 +1284,27 @@ class GangScheduler:
                     perm,
                     jnp.int32(-1),
                 )
-                state, n_bound = jax.lax.cond(
+                state, phase = jax.lax.cond(
                     n_pend > 0,
                     lambda s: preempt_phase(arrays, s, seg, order, weights),
-                    lambda s: (s, jnp.int32(0)),
+                    lambda s: (s, no_phase),
                     state,
                 )
+                n_bound = phase[1]
                 state, r2 = jax.lax.cond(
                     n_bound > 0,
                     lambda s: run(arrays, s, order, weights),
                     lambda s: (s, jnp.int32(0)),
                     state,
                 )
-                return state, rounds + r2, n_bound > 0
+                return state, rounds + r2, counts + phase, n_bound > 0
 
-            state, rounds, _ = jax.lax.while_loop(
-                lambda carry: carry[2],
+            state, rounds, counts, _ = jax.lax.while_loop(
+                lambda carry: carry[3],
                 obody,
-                (state, rounds, jnp.bool_(True)),
+                (state, rounds, no_phase, jnp.bool_(True)),
             )
-            return state, rounds
+            return state, (rounds, counts)
 
         # the fused one-dispatch pass exists only for the dynamic loop:
         # static mode's auto-resume budget is a host decision by design
@@ -1323,6 +1347,7 @@ class GangScheduler:
             self.run()
         self._final_state = None
         self._rounds = None
+        self._preempt_counts = None
         self._chronology = None
         self._trace = None
         self._recorded_weights = None
@@ -1348,9 +1373,12 @@ class GangScheduler:
             # decode fetch — this is the serving path (async overlap
             # depends on it staying sync-free). `rounds` stays a device
             # scalar; the finish path fetches it with the assignment.
-            state, rounds = self._fixpoint(arrays, self.enc.state0, order, w)
+            state, (rounds, counts) = self._fixpoint(
+                arrays, self.enc.state0, order, w
+            )
             self._final_state = state
             self._rounds = rounds
+            self._preempt_counts = counts
             return state, rounds
         if tracked and self._run_tracked is None:
             self._run_tracked = broker_mod.jit(
@@ -1419,6 +1447,7 @@ class GangScheduler:
             return state, total
 
         state, rounds = gang_pass(self.enc.state0)
+        counts = np.zeros(3, np.int64)  # preempt phases' (steps, bound, evicted)
         if self._preempt_phase is not None:
             order_np = np.asarray(order)
             while True:
@@ -1436,10 +1465,12 @@ class GangScheduler:
                 pad = 1 << int(pending.size - 1).bit_length()
                 seg = np.full((max(pad, 1),), -1, np.int32)
                 seg[: pending.size] = pending
-                state, n_bound = self._preempt_phase(
+                state, phase = self._preempt_phase(
                     arrays, state, jnp.asarray(seg), order, w
                 )
-                if int(np.asarray(n_bound)) == 0:
+                phase = np.asarray(phase)
+                counts += phase
+                if int(phase[1]) == 0:
                     break
                 state, r2 = gang_pass(state)
                 rounds = rounds + r2
@@ -1451,6 +1482,7 @@ class GangScheduler:
                 chronology.append(("leftover", leftovers.astype(np.int32)))
         self._final_state = state
         self._rounds = rounds
+        self._preempt_counts = counts
         if tracked:
             self._chronology = chronology
             self._recorded_weights = w
@@ -1486,7 +1518,7 @@ class GangScheduler:
     def _assemble_trace(self) -> tuple:
         """Replay the chronology into the sequential engine's trace slot
         layout ([Q, ...] per-queue-position tensors, sparse for the
-        [N, P] victim masks) so `BatchedScheduler.results()` decodes
+        [N, V] victim rows) so `BatchedScheduler.results()` decodes
         gang runs with zero new wire-format code."""
         from .engine import (
             TRACE_SLOTS_PREEMPT,
@@ -1522,8 +1554,8 @@ class GangScheduler:
             sparse: dict[str, dict] = {
                 n: {}
                 for n in (
-                    "pcode", "vmask", "codes2", "raw2", "final2",
-                    "pcode2", "vmask2",
+                    "pcode", "victims", "codes2", "raw2", "final2",
+                    "pcode2", "victims2",
                 )
             }
         if self._eval_rec is None:
@@ -1639,14 +1671,15 @@ class GangScheduler:
 
         if not has_pf:
             return (pf_codes, codes, raw, final, sel)
+        V = rec._preempt.victim_slots
         row_shapes = {
-            "pcode": ((N,), np.int32),
-            "vmask": ((N, P), bool),
-            "codes2": ((N, F), np.int32),
-            "raw2": ((N, S), sdt),
-            "final2": ((N, S), sdt),
-            "pcode2": ((N,), np.int32),
-            "vmask2": ((N, P), bool),
+            "pcode": ((N,), np.int32, 0),
+            "victims": ((N, V), np.int32, -1),
+            "codes2": ((N, F), np.int32, 0),
+            "raw2": ((N, S), sdt, 0),
+            "final2": ((N, S), sdt, 0),
+            "pcode2": ((N,), np.int32, 0),
+            "victims2": ((N, V), np.int32, -1),
         }
         by_name = {
             "pf_codes": pf_codes, "codes": codes, "raw": raw,
@@ -1657,8 +1690,7 @@ class GangScheduler:
         trace = []
         for i, name in enumerate(TRACE_SLOTS_PREEMPT):
             if i in TRACE_SPARSE_SLOTS:
-                shape, dtype = row_shapes[name]
-                trace.append(_SparseRows(sparse[name], shape, dtype))
+                trace.append(_SparseRows(sparse[name], *row_shapes[name]))
             else:
                 trace.append(by_name[name])
         return tuple(trace)
@@ -1712,6 +1744,7 @@ class GangScheduler:
         self.enc = enc
         self._final_state = None
         self._rounds = None
+        self._preempt_counts = None
         # record state is per-encoding. _run_tracked survives (its
         # shapes are part of the signature just checked); the recorder
         # and its chunk evaluator bake enc-derived statics via their own

@@ -125,19 +125,18 @@ class VolRestrictionsRow:
     def prepare(self, a, state, p):
         return ()
 
-    def node_init(self, a, ctx, state, vm, n):
-        vmi = vm.astype(jnp.int32)
+    def node_init(self, a, ctx, state, n):
         return {
-            "used_claims": state.used_claims - vmi @ a.pod_claim.astype(jnp.int32),
-            "disk_any": state.node_disk_any[n] - vmi @ a.pod_disk_any,
-            "disk_rw": state.node_disk_rw[n] - vmi @ a.pod_disk_rw,
+            "used_claims": state.used_claims,
+            "disk_any": state.node_disk_any[n],
+            "disk_rw": state.node_disk_rw[n],
         }
 
-    def add_back(self, a, ctx, cnt, v, n):
+    def add_back(self, a, ctx, cnt, v, n, by=1):
         return {
-            "used_claims": cnt["used_claims"] + a.pod_claim[v].astype(jnp.int32),
-            "disk_any": cnt["disk_any"] + a.pod_disk_any[v],
-            "disk_rw": cnt["disk_rw"] + a.pod_disk_rw[v],
+            "used_claims": cnt["used_claims"] + by * a.pod_claim[v].astype(jnp.int32),
+            "disk_any": cnt["disk_any"] + by * a.pod_disk_any[v],
+            "disk_rw": cnt["disk_rw"] + by * a.pod_disk_rw[v],
         }
 
     def check(self, a, ctx, cnt, p, n):
@@ -158,12 +157,11 @@ class _VolLimitsRow:
     def prepare(self, a, state, p):
         return ()
 
-    def node_init(self, a, ctx, state, vm, n):
-        vmi = vm.astype(jnp.int32)
-        return {"cnt": state.node_vol3[n, self.idx] - vmi @ a.pod_vol3[:, self.idx]}
+    def node_init(self, a, ctx, state, n):
+        return {"cnt": state.node_vol3[n, self.idx]}
 
-    def add_back(self, a, ctx, cnt, v, n):
-        return {"cnt": cnt["cnt"] + a.pod_vol3[v, self.idx]}
+    def add_back(self, a, ctx, cnt, v, n, by=1):
+        return {"cnt": cnt["cnt"] + by * a.pod_vol3[v, self.idx]}
 
     def check(self, a, ctx, cnt, p, n):
         want = a.pod_vol3[p, self.idx]

@@ -18,10 +18,10 @@ filter into:
   * `prepare`  — per preemption call, state-level: label/selector match
     matrices (assignment-independent) and base aggregation counters from
     the *current* assignment. O(P·T) once, matmul/scatter shaped.
-  * `node_init` — per candidate node: subtract the victims' contributions
-    from the base counters (victims all sit on the candidate node, so the
-    deltas collapse to one dot product + one scatter row).
-  * `add_back` — per reprieve step: one victim's O(T) counter delta.
+  * `node_init` — per candidate node: its counters as they stand.
+  * `add_back` — one resident's O(T) counter delta at the candidate node;
+    `by=-1` takes it off. The node's lower-priority residents (its slots,
+    below) are taken off one by one, then reprieved one by one.
   * `check`    — per reprieve step: feasibility of the preemptor on the
     candidate node from counters alone; no `[N]`-wide intermediates.
 
@@ -29,10 +29,19 @@ State-independent filters (NodeName, NodeUnschedulable, TaintToleration,
 NodeAffinity) are evaluated once per call with their ordinary kernels —
 victim removal cannot change them.
 
-Total cost: O(P·T) prepare + O(N·P) node-init (batched matmuls) +
-O(N·V·(T + NP1)) reprieve, where V is bounded by the max pods-per-node
-capacity — versus round 1's O(N²·V·F·N). The reprieve scan carry is a few
-KB of counters instead of the full cluster state.
+Per candidate node the dry run reads only that node's lower-priority
+residents: once per preemption call, one sort of the P pods keyed by
+(node, priority desc, bind order) lays every node's residents out in one
+run of the sorted order, in reprieve order — the slot table, slot k of
+node n at `order[start[n] + k]` (`slot_table`). The removal and reprieve
+loops gather one slot per node per step and walk as many slots as the
+fullest node holds residents (D), the candidates are ranked from what
+they gathered, and each node's victims land in a `[V]` row (V:
+`_victim_bound`); no value is `[N, P]` and no node sorts P pods.
+
+Total cost: O(P·T) prepare + O(P log P) slot table + O(N·D·(T + NP1))
+removal and reprieve — versus round 1's O(N²·V·F·N). The reprieve scan
+carry is a few KB of counters instead of the full cluster state.
 """
 
 from __future__ import annotations
@@ -71,22 +80,18 @@ class _FitRow:
     """NodeResourcesFit under victim removal (oracle fit_filter)."""
 
     def __init__(self, enc: EncodedCluster):
-        self.res_dt = enc.policy.res
+        pass
 
     def prepare(self, a: ClusterArrays, state: SchedState, p):
         return ()
 
-    def node_init(self, a, ctx, state, vm, n):
-        vmf = vm.astype(self.res_dt)
-        return {
-            "requested": state.requested[n] - vmf @ a.pod_req,
-            "n_pods": state.n_pods[n] - vm.sum(dtype=jnp.int32),
-        }
+    def node_init(self, a, ctx, state, n):
+        return {"requested": state.requested[n], "n_pods": state.n_pods[n]}
 
-    def add_back(self, a, ctx, cnt, v, n):
+    def add_back(self, a, ctx, cnt, v, n, by=1):
         return {
-            "requested": cnt["requested"] + a.pod_req[v],
-            "n_pods": cnt["n_pods"] + 1,
+            "requested": cnt["requested"] + by * a.pod_req[v],
+            "n_pods": cnt["n_pods"] + by,
         }
 
     def check(self, a, ctx, cnt, p, n):
@@ -105,19 +110,18 @@ class _PortsRow:
     def prepare(self, a, state, p):
         return ()
 
-    def node_init(self, a, ctx, state, vm, n):
-        vmi = vm.astype(jnp.int32)
+    def node_init(self, a, ctx, state, n):
         return {
-            "used_pair": state.used_pair[n] - vmi @ a.want_pair,
-            "used_wild": state.used_wild[n] - vmi @ a.want_wild,
-            "used_trip": state.used_trip[n] - vmi @ a.want_trip,
+            "used_pair": state.used_pair[n],
+            "used_wild": state.used_wild[n],
+            "used_trip": state.used_trip[n],
         }
 
-    def add_back(self, a, ctx, cnt, v, n):
+    def add_back(self, a, ctx, cnt, v, n, by=1):
         return {
-            "used_pair": cnt["used_pair"] + a.want_pair[v],
-            "used_wild": cnt["used_wild"] + a.want_wild[v],
-            "used_trip": cnt["used_trip"] + a.want_trip[v],
+            "used_pair": cnt["used_pair"] + by * a.want_pair[v],
+            "used_wild": cnt["used_wild"] + by * a.want_wild[v],
+            "used_trip": cnt["used_trip"] + by * a.want_trip[v],
         }
 
     def check(self, a, ctx, cnt, p, n):
@@ -190,19 +194,15 @@ class _SpreadRow:
             "hc_ix": hc_ix[:, 0],
         }
 
-    def node_init(self, a, ctx, state, vm, n):
-        # victims all sit on node n: their per-constraint contribution is a
-        # dot product, scattered at node n's topology values.
-        delta = (ctx["m_live"] @ vm.astype(jnp.int32)) * ctx["elig"][n].astype(
-            jnp.int32
-        )  # [HC]
-        pairs_n = ctx["pairs_all"][n]
-        return {"cnt": ctx["base_cnt"].at[ctx["hc_ix"], pairs_n].add(-delta)}
+    def node_init(self, a, ctx, state, n):
+        return {"cnt": ctx["base_cnt"]}
 
-    def add_back(self, a, ctx, cnt, v, n):
+    def add_back(self, a, ctx, cnt, v, n, by=1):
+        # v sits on node n: its per-constraint contribution lands at node
+        # n's topology values
         d = ctx["m_live"][:, v].astype(jnp.int32) * ctx["elig"][n].astype(jnp.int32)
         pairs_n = ctx["pairs_all"][n]
-        return {"cnt": cnt["cnt"].at[ctx["hc_ix"], pairs_n].add(d)}
+        return {"cnt": cnt["cnt"].at[ctx["hc_ix"], pairs_n].add(by * d)}
 
     def check(self, a, ctx, cnt, p, n):
         c = cnt["cnt"]
@@ -296,33 +296,24 @@ class _InterpodRow:
             "has_terms": f3["valid"].any(),
         }
 
-    def node_init(self, a, ctx, state, vm, n):
-        rel = a.rel
-        vmi = vm.astype(jnp.int32)
-        # (1): victims' own anti-affinity contributions leave with them
-        w1 = (ctx["contrib1"] & vm[:, None] & (ctx["pair_ot"] > 0)).astype(jnp.int32)
-        ea = ctx["ea_base"].at[ctx["pair_ot"]].add(-w1)
-        out = {"ea": ea}
-        for fk in ("f2", "f3"):
-            f = ctx[fk]
-            npair_row = f["npair_n"][n]  # [T] — victims all sit on node n
-            delta = f["m"] @ vmi  # [T]
-            delta = delta * (npair_row > 0)
-            out[fk] = f["base"].at[f["t_ix"], npair_row].add(-delta)
-        out["total3"] = ctx["total3"] - (
-            (ctx["f3"]["m"] @ vmi) * (ctx["f3"]["npair_n"][n] > 0)
-        ).sum()
-        return out
+    def node_init(self, a, ctx, state, n):
+        return {
+            "ea": ctx["ea_base"],
+            "f2": ctx["f2"]["base"],
+            "f3": ctx["f3"]["base"],
+            "total3": ctx["total3"],
+        }
 
-    def add_back(self, a, ctx, cnt, v, n):
+    def add_back(self, a, ctx, cnt, v, n, by=1):
+        # (1): v's own anti-affinity contributions come and go with it
         w1 = (ctx["contrib1"][v] & (ctx["pair_ot"][v] > 0)).astype(jnp.int32)
-        out = {"ea": cnt["ea"].at[ctx["pair_ot"][v]].add(w1)}
+        out = {"ea": cnt["ea"].at[ctx["pair_ot"][v]].add(by * w1)}
         for fk in ("f2", "f3"):
             f = ctx[fk]
-            npair_row = f["npair_n"][n]
+            npair_row = f["npair_n"][n]  # [T] — v sits on node n
             d = f["m"][:, v].astype(jnp.int32) * (npair_row > 0)
-            out[fk] = cnt[fk].at[f["t_ix"], npair_row].add(d)
-        out["total3"] = cnt["total3"] + (
+            out[fk] = cnt[fk].at[f["t_ix"], npair_row].add(by * d)
+        out["total3"] = cnt["total3"] + by * (
             ctx["f3"]["m"][:, v].astype(jnp.int32) * (ctx["f3"]["npair_n"][n] > 0)
         ).sum()
         return out
@@ -376,11 +367,11 @@ def _victim_bound(enc: EncodedCluster, filter_names) -> int:
     node ever holds more pods than max(pods capacity, its initial load).
 
     Rounded UP to the geometric shape bucket: the bound is baked into
-    the compiled program (it sizes the reprieve scan), and the raw value
-    moves with the initial per-node load — exact, it would recompile as
-    churn shifts pods around. Over-approximation is safe: the extra
-    reprieve slots carry sort-key sentinels (vm[v] False) and are exact
-    no-ops."""
+    the compiled program (it sizes each node's victim row and caps the
+    reprieve), and the raw value moves with the initial per-node load —
+    exact, it would recompile as churn shifts pods around.
+    Over-approximation is safe: the reprieve walks only the slots the
+    fullest node fills, and the rest of each row stays -1."""
     from ..utils.compilecache import shape_bucket
 
     P = enc.P
@@ -396,12 +387,38 @@ def _victim_bound(enc: EncodedCluster, filter_names) -> int:
     return min(P, shape_bucket(raw, lo=1))
 
 
+def slot_table(state: SchedState, lower, priority, N: int):
+    """Each node's `lower` residents in reprieve order (priority desc, then
+    bind order asc: oracle NodeInfo.pods insertion order for ties; pod
+    index breaks what is left, as a stable per-node sort would) as runs of
+    one sorted order: `(order [P], start [N], count [N])`, slot k of node n
+    being `order[start[n] + k]` for k < count[n]. One stable sort of P
+    keyed by (node, -priority, bound_seq)."""
+    node_key = jnp.where(lower, state.assignment, N)  # non-residents last
+    order = jnp.lexsort((state.bound_seq, -priority, node_key)).astype(jnp.int32)
+    sorted_node = node_key[order]
+    nodes = jnp.arange(N, dtype=node_key.dtype)
+    start = jnp.searchsorted(sorted_node, nodes, side="left").astype(jnp.int32)
+    end = jnp.searchsorted(sorted_node, nodes, side="right").astype(jnp.int32)
+    return order, start, end - start
+
+
+def nominated_victims(victims, nominated, P: int):
+    """The `[P]` eviction mask of the nominated node's victims (all
+    False when nothing was nominated)."""
+    row = victims[jnp.maximum(nominated, 0)]
+    row = jnp.where((row >= 0) & (nominated >= 0), row, P)
+    return jnp.zeros(P + 1, bool).at[row].set(True)[:P]
+
+
 def build_preemption(enc: EncodedCluster, filter_names):
-    """Returns preempt(a, state, p) -> (pf_code [N] int32, victim_mask
-    [N, P] bool, nominated int32)."""
+    """Returns preempt(a, state, p) -> (pf_code [N] int32, victims [N, V]
+    int32, nominated int32). `victims[n]` holds the pod indices node n
+    would evict, in reprieve order, -1-padded; `nominated_victims` turns the
+    nominated node's into the `[P]` eviction mask. `preempt.victim_slots` is
+    V."""
     from . import kernels as K
 
-    P = enc.P
     BIG = jnp.iinfo(jnp.int32).max
     row_filters = []
     static_kernels = []
@@ -432,14 +449,17 @@ def build_preemption(enc: EncodedCluster, filter_names):
         for k in static_kernels:
             static_ok = static_ok & (k(a, state, p) == 0)
         ctxs = [rf.prepare(a, state, p) for rf in row_filters]
+        order, start, count = slot_table(state, lower_all, a.pod_priority, N)
+        P = order.shape[0]
+        # the loops walk as many slots as the fullest node has residents:
+        # past them every node's slots are empty (the reprieve stops at V,
+        # the most any node can hold)
+        depth = count.max()
+        sum_dt = jax.eval_shape(jnp.sum, a.pod_priority).dtype
 
-        def eval_node(n):
-            vm = lower_all & (state.assignment == n)
-            any_lower = vm.any()
-            cnts = tuple(
-                rf.node_init(a, ctx, state, vm, n)
-                for rf, ctx in zip(row_filters, ctxs)
-            )
+        def eval_node(n, lo, cnt_n):
+            def slot(k):
+                return order[jnp.minimum(lo + k, P - 1)], k < cnt_n
 
             def feasible(cnts_now):
                 ok = static_ok[n]
@@ -447,34 +467,51 @@ def build_preemption(enc: EncodedCluster, filter_names):
                     ok = ok & rf.check(a, ctx, cnt, p, n)
                 return ok
 
-            fits = feasible(cnts)
-            # reprieve order: priority desc, bind order asc (oracle
-            # NodeInfo.pods insertion order for ties)
-            sort_prio = jnp.where(vm, -a.pod_priority, BIG)
-            sort_seq = jnp.where(vm, state.bound_seq, BIG)
-            order = jnp.lexsort((sort_seq, sort_prio))[:V]
-
-            def reprieve(carry, v):
-                cnts_c, victims = carry
-                valid = vm[v]
-                cnts_try = tuple(
-                    rf.add_back(a, ctx, cnt, v, n)
+            def step(cnts_c, v, by):
+                return tuple(
+                    rf.add_back(a, ctx, cnt, v, n, by)
                     for rf, ctx, cnt in zip(row_filters, ctxs, cnts_c)
                 )
+
+            def remove(k, cnts_c):
+                v, live = slot(k)
+                return jax.tree.map(
+                    lambda x, y: jnp.where(live, x, y), step(cnts_c, v, -1), cnts_c
+                )
+
+            cnts = tuple(
+                rf.node_init(a, ctx, state, n) for rf, ctx in zip(row_filters, ctxs)
+            )
+            cnts = jax.lax.fori_loop(0, depth, remove, cnts)
+            fits = feasible(cnts)
+
+            def reprieve(k, carry):
+                cnts_c, victims, maxp, sump = carry
+                v, live = slot(k)
+                cnts_try = step(cnts_c, v, 1)
                 ok = feasible(cnts_try)
-                keep = valid & ok
+                keep = live & ok
                 cnts_c = jax.tree.map(
                     lambda x, y: jnp.where(keep, x, y), cnts_try, cnts_c
                 )
-                victims = victims.at[v].set(valid & ~ok)
-                return (cnts_c, victims), None
+                out = live & ~ok
+                prio = a.pod_priority[v]
+                return (
+                    cnts_c,
+                    victims.at[k].set(jnp.where(out, v, -1)),
+                    jnp.where(out, jnp.maximum(maxp, prio), maxp),
+                    sump + jnp.where(out, prio, 0).astype(sum_dt),
+                )
 
-            (_, victims), _ = jax.lax.scan(
-                reprieve, (cnts, jnp.zeros(P, bool)), order
+            _, victims, maxp, sump = jax.lax.fori_loop(
+                0,
+                jnp.minimum(depth, V),
+                reprieve,
+                (cnts, jnp.full(V, -1, jnp.int32), jnp.int32(-BIG), jnp.zeros((), sum_dt)),
             )
-            has_victims = victims.any()
+            has_victims = (victims >= 0).any()
             code = jnp.where(
-                ~any_lower,
+                cnt_n == 0,
                 PREEMPT_NO_LOWER,
                 jnp.where(
                     ~fits,
@@ -485,17 +522,16 @@ def build_preemption(enc: EncodedCluster, filter_names):
             # SILENT: fits with zero surviving victims (possible when the
             # infeasibility came from another node via spread/inter-pod
             # coupling) — the oracle records no message and no candidate.
-            victims = victims & (code == PREEMPT_CANDIDATE)
-            return code.astype(jnp.int32), victims
+            victims = jnp.where(code == PREEMPT_CANDIDATE, victims, -1)
+            return code.astype(jnp.int32), victims, maxp, sump
 
-        pf_code, victim_mask = jax.vmap(eval_node)(jnp.arange(N))  # [N], [N, P]
-        # node choice (oracle rank): min highest-victim-priority, then min
+        pf_code, victims, maxp, sump = jax.vmap(eval_node)(
+            jnp.arange(N), start, count
+        )  # [N], [N, V], [N], [N]
+        # node choice (oracle rank): min highest-victim priority, then min
         # priority sum, then fewest victims, then lowest node index
         cand = pf_code == PREEMPT_CANDIDATE
-        prios = jnp.where(victim_mask, a.pod_priority[None, :], 0)
-        maxp = jnp.where(victim_mask, a.pod_priority[None, :], -BIG).max(axis=1)
-        sump = prios.sum(axis=1)
-        cnt = victim_mask.sum(axis=1)
+        cnt = (victims >= 0).sum(axis=1)
         alive = cand
         for key in (maxp, sump, cnt):
             best = jnp.where(alive, key, BIG).min()
@@ -506,8 +542,9 @@ def build_preemption(enc: EncodedCluster, filter_names):
             PREEMPT_SELECTED,
             pf_code,
         )
-        return pf_code, victim_mask, nominated
+        return pf_code, victims, nominated
 
+    preempt.victim_slots = V
     return preempt
 
 

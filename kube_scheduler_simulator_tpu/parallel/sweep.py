@@ -21,6 +21,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ..utils import broker as broker_mod
 from ..engine.encode import EncodedCluster
 from ..engine.engine import BatchedScheduler
+from ..engine.preempt import nominated_victims
 from .shard import shard_encoded
 
 
@@ -43,7 +44,7 @@ class WeightSweep:
     """vmap'd scheduling sweep over score-weight variants.
 
     DefaultPreemption runs as a TWO-PHASE event loop by default
-    (`preempt="phase"`): the scan itself never carries the [N, P] victim
+    (`preempt="phase"`): the scan itself never carries the [N, V] victim
     dry-run — it runs preemption-off and STOPS at each variant's first
     preemption-eligible failure; a compiled single-pod preemption program
     (dry-run → evict → retry → bind, the engine step's exact preempt
@@ -182,8 +183,8 @@ class WeightSweep:
 
         def preempt_one(arrays, state, p, qi, weights, valid):
             a = arrays
-            _, vmask, nominated = preempt_fn(a, state, p)
-            evict = vmask[jnp.maximum(nominated, 0)] & (nominated >= 0)
+            _, victims, nominated = preempt_fn(a, state, p)
+            evict = nominated_victims(victims, nominated, a.pod_mask.shape[0])
             st2 = evict_all(state, a, evict)
             *_, sel2, _ = attempt(st2, a, weights, p)
             # nomination failed -> terminally unschedulable (sel -1);
@@ -431,8 +432,9 @@ class GangSweep:
                 segs_j = jax.device_put(
                     segs_j, NamedSharding(self.mesh, P("replicas", None))
                 )
-            states, n_bound = self._vphase(arrays, states, segs_j, order, wj)
-            if int(np.asarray(n_bound).sum()) == 0:
+            states, phase = self._vphase(arrays, states, segs_j, order, wj)
+            # phase[:, 1]: pods each variant's phase bound
+            if int(np.asarray(phase)[:, 1].sum()) == 0:
                 break
             states, r2 = gang_pass(states, initial=False)
             rounds = rounds + r2

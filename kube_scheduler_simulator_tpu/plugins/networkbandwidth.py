@@ -218,11 +218,11 @@ class _NBRow:
         )
         return {"allocated": allocated}
 
-    def node_init(self, a, ctx, state, vm, n):
-        return {"alloc_n": ctx["allocated"][n] - vm.astype(self.bw.dtype) @ self.bw}
+    def node_init(self, a, ctx, state, n):
+        return {"alloc_n": ctx["allocated"][n]}
 
-    def add_back(self, a, ctx, cnt, v, n):
-        return {"alloc_n": cnt["alloc_n"] + self.bw[v]}
+    def add_back(self, a, ctx, cnt, v, n, by=1):
+        return {"alloc_n": cnt["alloc_n"] + by * self.bw[v]}
 
     def check(self, a, ctx, cnt, p, n):
         want = self.bw[p]

@@ -265,7 +265,7 @@ class BatchPlane:
         record trace. ``kind="gang"``: `queue` is the [P] PrioritySort
         order tensor, the program vmaps `fixpoint_fn`
         (``batch.gang.run``), and the second slice is the pass's
-        rounds-to-fixpoint scalar."""
+        (rounds-to-fixpoint scalar, preempt-phase counts)."""
         me = _Enrollee(engine, queue, session_id, metrics)
         with self._lock:
             if self._draining:
@@ -430,8 +430,8 @@ class BatchPlane:
         dt = time.perf_counter() - t0
         for i, it in enumerate(items):
             it.state = jax.tree.map(lambda x, i=i: x[i], state_out)
-            # gang's second output is the rounds-to-fixpoint scalar;
-            # tree.map slices both shapes identically
+            # gang's second output is (rounds scalar, preempt counts);
+            # tree.map slices every shape identically
             it.trace = jax.tree.map(lambda x, i=i: x[i], trace_out)
         # -- accounting -----------------------------------------------------
         # enrollees whose done-wait already expired are dispatching solo

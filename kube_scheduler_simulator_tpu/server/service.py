@@ -875,15 +875,17 @@ class SchedulerService:
         # They are NOT in placements (decode covers queued pods only), so
         # diff the full [P] assignment exactly like the sequential path —
         # upstream preemption deletes victims through the API. One
-        # device_get fetches both sides of the diff and the rounds
-        # scalar: the host's one block on the device program; placements
-        # decode reads the already-landed `after` rows (no second sync).
+        # device_get fetches both sides of the diff, the rounds scalar and
+        # the preempt phases' counts: the host's one block on the device
+        # program; placements decode reads the already-landed `after`
+        # rows (no second sync).
         with telemetry.span("pass.device_wait"):
-            before, after, rounds = jax.device_get(
+            before, after, rounds, preempt = jax.device_get(
                 (
                     enc.state0.assignment,
                     gang._final_state.assignment,
                     gang._rounds,
+                    gang._preempt_counts,
                 )
             )
         self._fetched_s = time.perf_counter()  # results on the host
@@ -901,13 +903,13 @@ class SchedulerService:
             after = np.asarray(after)
             placements = gang.enc.decode_assignment(after)
             rounds = int(np.asarray(rounds))
-            # booked here, not at dispatch: the rounds scalar stays on
-            # device until this finish-path fetch (async overlap depends
-            # on the dispatch staying sync-free)
+            steps, bound, evicted = (int(x) for x in np.asarray(preempt))
+            # booked here, not at dispatch: the rounds scalar and the
+            # phase counts stay on device until this finish-path fetch
+            # (async overlap depends on the dispatch staying sync-free)
             self.metrics.record_gang(fixpoint_rounds=rounds)
-            for p_idx in np.nonzero((before >= 0) & (after < 0))[0]:
-                ns, name = enc.pod_keys[int(p_idx)]
-                self.store.delete("pods", name, ns)
+            self.metrics.record_preempt(steps=steps, bound=bound, victims=evicted)
+            self._delete_victims(enc, before, after)
             if results is not None:
                 self._write_back_records(results, placements)
             else:
@@ -1481,7 +1483,7 @@ class SchedulerService:
         if out is None:
             self._count_solo_fallback()
             return None
-        gang._final_state, gang._rounds = out
+        gang._final_state, (gang._rounds, gang._preempt_counts) = out
         return (enc, gang)
 
     def _seq_finish(self, disp) -> list[PodSchedulingResult]:
@@ -1529,9 +1531,8 @@ class SchedulerService:
             before = np.asarray(before)
             after = np.asarray(after)
             placements = enc.decode_assignment(after)
-            for p_idx in np.nonzero((before >= 0) & (after < 0))[0]:
-                ns, name = enc.pod_keys[int(p_idx)]
-                self.store.delete("pods", name, ns)
+            self._book_seq_preempt(results, placements)
+            self._delete_victims(enc, before, after)
             self._write_back_seq(results, placements, ext_service)
         self.metrics.record_phase_seconds(
             decode=time.perf_counter() - t_decode
@@ -1542,6 +1543,36 @@ class SchedulerService:
             "extender" if kind == "ext" else "sequential",
         )
         return results
+
+    def _delete_victims(self, enc, before, after) -> None:
+        """Delete the pass's preemption victims from the store: pods bound
+        before the pass and unbound after it (upstream preemption deletes
+        victims through the API)."""
+        import numpy as np
+
+        with telemetry.span("pass.victims"):
+            for p_idx in np.nonzero((before >= 0) & (after < 0))[0]:
+                ns, name = enc.pod_keys[int(p_idx)]
+                self.store.delete("pods", name, ns)
+
+    def _book_seq_preempt(self, results, placements: dict) -> None:
+        """The preempt counters for a sequential pass, from its records:
+        a step is a pod whose dry run ran (it left DefaultPreemption
+        messages or was nominated), a nominated pod that ended on a node
+        is bound, and the nominated records' victims were evicted."""
+        ran: set = set()
+        bound = evicted = 0
+        for res in results:
+            key = (res.pod_namespace, res.pod_name)
+            nominated = res.status == "Nominated"
+            if nominated:
+                evicted += len(res.preemption_victims)
+                bound += bool(placements.get(key))
+            if nominated or any(
+                "DefaultPreemption" in msgs for msgs in res.post_filter.values()
+            ):
+                ran.add(key)
+        self.metrics.record_preempt(steps=len(ran), bound=bound, victims=evicted)
 
     def _write_back_seq(self, results, placements: dict, ext_service) -> None:
         """Write results back onto the pod objects (last record per pod

@@ -296,8 +296,14 @@ def speculation_enabled_default() -> bool:
     )
 
 
+# the occupancy watermark of a shape bucket: past it the next bucket up
+# is compiled ahead; a count that fits the next bucket down within it is
+# re-encoded there (engine/delta.py `DeltaEncoder`)
+BUCKET_WATERMARK = 0.8
+
+
 def adjacent_bucket_targets(
-    live: int, bucket: int, *, lo: int = 8, up_frac: float = 0.8
+    live: int, bucket: int, *, lo: int = 8, up_frac: float = BUCKET_WATERMARK
 ) -> list[int]:
     """The shape buckets worth pre-compiling for, given `live` objects in
     the current `bucket`: the next power-of-two UP once occupancy passes

@@ -302,6 +302,12 @@ class SchedulingMetrics:
     # served by a batched dispatch (batch.gang.run) — both per-session
     _gang_fixpoint_rounds: int = 0
     _batched_gang_passes: int = 0
+    # preemption (engine/gang.py preempt phase; the sequential finish
+    # books the same from its records): pods that went through the
+    # phase, pods it bound, pods it evicted — per-session
+    _gang_preempt_steps: int = 0
+    _gang_preempt_bound: int = 0
+    _gang_preempt_victims: int = 0
     # latency-distribution state (the observability PR): Prometheus-style
     # histograms behind the same lock as the counters, rendered into the
     # JSON snapshot's `histograms` block and the exposition text
@@ -617,6 +623,15 @@ class SchedulingMetrics:
             self._gang_fixpoint_rounds += int(fixpoint_rounds)
             self._batched_gang_passes += int(batched_passes)
 
+    def record_preempt(self, *, steps: int, bound: int, victims: int) -> None:
+        """Preemption accounting of one pass: `steps` pods went through
+        the preempt phase (a sequential pass: pods whose dry run ran),
+        `bound` of them were bound, `victims` pods were evicted."""
+        with self._lock:
+            self._gang_preempt_steps += int(steps)
+            self._gang_preempt_bound += int(bound)
+            self._gang_preempt_victims += int(victims)
+
     def record_phase_seconds(
         self, execute: float = 0.0, decode: float = 0.0
     ) -> None:
@@ -713,6 +728,9 @@ class SchedulingMetrics:
                     "soloFallbacks": self._solo_fallbacks,
                     "gangFixpointRounds": self._gang_fixpoint_rounds,
                     "batchedGangPasses": self._batched_gang_passes,
+                    "gangPreemptSteps": self._gang_preempt_steps,
+                    "gangPreemptBound": self._gang_preempt_bound,
+                    "gangPreemptVictims": self._gang_preempt_victims,
                 },
                 # derived continuous-batching view (server/batchplane.py):
                 # mean window fill — a ratio, so it lives outside the
@@ -776,6 +794,9 @@ class SchedulingMetrics:
             self._solo_fallbacks = 0
             self._gang_fixpoint_rounds = 0
             self._batched_gang_passes = 0
+            self._gang_preempt_steps = 0
+            self._gang_preempt_bound = 0
+            self._gang_preempt_victims = 0
             self._slo_skip_eager = 0
             self._slo_skip_degraded = 0
             self._hist = _new_histograms()
@@ -797,6 +818,7 @@ class SchedulingMetrics:
         "_aot_deserialize_s",
         "_batched_passes", "_batch_windows", "_batch_occupancy_sum",
         "_solo_fallbacks", "_gang_fixpoint_rounds", "_batched_gang_passes",
+        "_gang_preempt_steps", "_gang_preempt_bound", "_gang_preempt_victims",
     )
 
     def state_dict(self) -> dict:
@@ -1004,6 +1026,21 @@ _PROM_COUNTERS = (
         "kss_batched_gang_passes_total",
         "Gang passes served by a cross-tenant batched dispatch.",
         ("phases", "batchedGangPasses"),
+    ),
+    (
+        "kss_gang_preempt_steps_total",
+        "Pods that went through a preempt phase (dry run attempted).",
+        ("phases", "gangPreemptSteps"),
+    ),
+    (
+        "kss_gang_preempt_bound_total",
+        "Pods bound by preempt phases.",
+        ("phases", "gangPreemptBound"),
+    ),
+    (
+        "kss_gang_preempt_victims_total",
+        "Pods evicted by preemption.",
+        ("phases", "gangPreemptVictims"),
     ),
 )
 

@@ -27,19 +27,24 @@ from kube_scheduler_simulator_tpu.lifecycle.engine import LifecycleEngine
 from kube_scheduler_simulator_tpu.models.store import ResourceStore
 from kube_scheduler_simulator_tpu.scenario.chaos import ChaosSpec
 from kube_scheduler_simulator_tpu.sched.config import SchedulerConfiguration
+from kube_scheduler_simulator_tpu.utils.broker import BUCKET_WATERMARK
 from kube_scheduler_simulator_tpu.utils.compilecache import capacity_buckets
 
 from helpers import node, pod
 
 
-def full_encode(store, config, *, node_lo=8, pod_lo=8, policy=TPU32):
+def full_encode(
+    store, config, *, node_lo=8, pod_lo=8, policy=TPU32, pod_capacity=None
+):
     """The from-scratch reference: exactly what the service's full path
-    builds for this store state."""
+    builds for this store state (at `pod_capacity` when given: a pod
+    bucket the encoder kept on a shrink)."""
     nodes = store.list("nodes")
     pods = store.list("pods")
     ncap, pcap = capacity_buckets(
         len(nodes), len(pods), node_lo=node_lo, pod_lo=pod_lo
     )
+    pcap = pod_capacity or pcap
     return encode_cluster(
         nodes,
         pods,
@@ -84,11 +89,22 @@ def check(delta, store, config, ctx=""):
     if enc is not None:
         assert retained is enc
     if retained is not None:
+        # the pod bucket is the fresh one, or a larger one kept because
+        # the count still exceeds the watermark of the bucket below it
+        n_pods = len(store.list("pods"))
+        fresh = capacity_buckets(len(store.list("nodes")), n_pods)[1]
+        assert retained.P == fresh or (
+            retained.P > fresh and n_pods > BUCKET_WATERMARK * (retained.P // 2)
+        ), ctx
         # the reference is a from-scratch encode under the encoder's OWN
         # policy, so under PACKED the comparison covers the packed words
         # and narrowed dtypes bit-for-bit
         assert_enc_equal(
-            retained, full_encode(store, config, policy=delta.policy), ctx
+            retained,
+            full_encode(
+                store, config, policy=delta.policy, pod_capacity=retained.P
+            ),
+            ctx,
         )
     else:
         # nothing retained: legitimately nothing schedulable right now
@@ -326,6 +342,41 @@ def test_bucket_crossing_falls_back_and_grows_shapes():
     info = check(delta, store, cfg, "crossing")
     assert info["mode"] == "full" and "bucket" in info["reason"]
     assert delta._st.enc.P == 16
+
+
+@pytest.mark.parametrize(
+    "start,steps",
+    [
+        # a count above 0.8 of the bucket below keeps the bucket
+        (20, [(20, 32), (16, 32), (13, 32), (12, 16), (6, 8)]),
+        # a count far below drops straight to its own bucket
+        (40, [(40, 64), (26, 64), (10, 16)]),
+    ],
+)
+def test_pod_bucket_hysteresis_on_shrink(start, steps):
+    """Deletions re-encode in full; the pod capacity shrinks only once
+    the count fits the bucket below within the bucket watermark, so a
+    count that swings across a power of two keeps one program. Every
+    encoding is still exactly the from-scratch one at its capacity."""
+    store = ResourceStore()
+    cfg = SchedulerConfiguration.default()
+    store.apply("nodes", node("n0", cpu="64", pods="200"))
+    for i in range(start):
+        store.apply("pods", pod(f"p{i}"))
+    delta = DeltaEncoder()
+    live = start
+    for count, want in steps:
+        while live > count:
+            live -= 1
+            store.delete("pods", f"p{live}")
+        enc, _ = delta.encode(store, cfg)
+        assert (enc.n_pods, enc.P) == (count, want)
+        nodes, pods = store.list("nodes"), store.list("pods")
+        ref = encode_cluster(
+            nodes, pods, cfg, policy=TPU32,
+            node_capacity=capacity_buckets(len(nodes), len(pods))[0], pod_capacity=enc.P,
+        )
+        assert_enc_equal(enc, ref, f"{count} pods")
 
 
 def test_config_identity_change_falls_back():
