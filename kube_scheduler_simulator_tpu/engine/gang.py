@@ -101,6 +101,136 @@ from .preempt import nominated_victims
 _NO_ORDER = jnp.iinfo(jnp.int32).max
 
 
+def match_candidates(
+    vals, idx, order_v, pod_claim_v, c_min, *, n_nodes, inner_iters,
+    inner_static, neg,
+):
+    """One round's matching: argmax over untaken candidates → per-node
+    earliest-order winner → per-claim earliest-order winner → commit,
+    repeated until an iteration commits nothing (`inner_static`: a
+    counted scan) or `inner_iters` iterations have run.
+
+    `vals` [K, k] are the rows' candidate scores (`neg` where
+    infeasible or masked), `idx` [K, k] int32 their node indices, or
+    None for full width (column == node, k == `n_nodes`). `order_v` [K]
+    global queue positions, `pod_claim_v` [K, C] bool claim use,
+    `c_min` the carrier cut (only rows with `order_v < c_min` may
+    commit) or None. Returns (sel [K] int32 node or -1, iters int32:
+    the matching iterations run in top-k mode, 0 at full width).
+
+    Top-k rows come from `lax.top_k`, so each row is sorted descending,
+    and within a round everything that removes a candidate only grows:
+    `taken`, `claim_taken`, the committed rows. So a row's argmax over
+    its untaken candidates is its FIRST untaken position, and that
+    position never moves back: each row carries a cursor `cur` that an
+    iteration advances past taken nodes ([K]-wide lookups) instead of
+    re-reading `taken` at all [K, k] candidates."""
+    N = n_nodes
+    K, k = vals.shape
+
+    def resolve(taken, claim_taken, sel_acc, has, cand):
+        tgt = jnp.where(has, cand, N)
+        winner = (
+            jnp.full((N + 1,), _NO_ORDER, jnp.int32).at[tgt].min(order_v)
+        )
+        commit = has & (winner[jnp.maximum(cand, 0)] == order_v)
+        claim_order = jnp.where(
+            commit[:, None] & pod_claim_v, order_v[:, None], _NO_ORDER
+        )
+        claim_min = claim_order.min(axis=0)  # [C]
+        claim_ok = jnp.where(
+            pod_claim_v, claim_min[None, :] == order_v[:, None], True
+        ).all(axis=1)
+        commit = commit & claim_ok
+        sel_acc = jnp.where(commit, cand, sel_acc)
+        taken = taken | (
+            jnp.zeros((N + 1,), bool)
+            .at[jnp.where(commit, cand, N)]
+            .set(True)[:N]
+        )
+        claim_taken = claim_taken | (pod_claim_v & commit[:, None]).any(axis=0)
+        return taken, claim_taken, sel_acc, commit.any()
+
+    def full_step(carry):
+        taken, claim_taken, sel_acc = carry
+        m = jnp.where(taken[None, :], neg, vals)
+        m = jnp.where((sel_acc >= 0)[:, None], neg, m)
+        claim_blocked = (pod_claim_v & claim_taken[None, :]).any(axis=1)
+        m = jnp.where(claim_blocked[:, None], neg, m)
+        if c_min is not None:
+            # queue-prefix batching: the batched matching may only
+            # commit pods strictly BEFORE the first placeable carrier in
+            # queue order — carriers (and everything behind them) wait,
+            # preserving the sequential interleaving at carrier
+            # boundaries
+            m = jnp.where((order_v >= c_min)[:, None], neg, m)
+        col = jnp.argmax(m, axis=1).astype(jnp.int32)
+        has = jnp.take_along_axis(m, col[:, None], axis=1)[:, 0] > neg
+        taken, claim_taken, sel_acc, changed = resolve(
+            taken, claim_taken, sel_acc, has, col
+        )
+        return (taken, claim_taken, sel_acc), changed
+
+    def cursor_step(carry):
+        taken, claim_taken, sel_acc, cur = carry
+        # rows that may still commit this round; a row masked once stays
+        # masked (its cursor is never read again)
+        live = (sel_acc < 0) & ~(pod_claim_v & claim_taken[None, :]).any(
+            axis=1
+        )
+        if c_min is not None:
+            live = live & (order_v < c_min)
+
+        def look(cur):
+            # (cur, node at cur, row proposes, row's node is taken); the
+            # pick at cur is a one-hot select-sum over k, which the chip
+            # runs faster than a [K] take_along_axis gather
+            hot = jnp.arange(k, dtype=jnp.int32)[None, :] == cur[:, None]
+            v = jnp.where(hot, vals, 0).sum(axis=1, dtype=vals.dtype)
+            node = jnp.where(hot, idx, 0).sum(axis=1, dtype=idx.dtype)
+            has = live & (cur < k) & (v > neg)
+            return cur, node, has, has & taken[node]
+
+        cur, cand, has, _ = jax.lax.while_loop(
+            lambda c: c[3].any(), lambda c: look(c[0] + c[3]), look(cur)
+        )
+        taken, claim_taken, sel_acc, changed = resolve(
+            taken, claim_taken, sel_acc, has, cand
+        )
+        return (taken, claim_taken, sel_acc, cur), changed
+
+    carry0 = (
+        jnp.zeros((N,), bool),
+        jnp.zeros(pod_claim_v.shape[1:], bool),
+        jnp.full((K,), -1, jnp.int32),
+    )
+    if idx is None:
+        step = full_step
+    else:
+        step = cursor_step
+        carry0 = carry0 + (jnp.zeros((K,), jnp.int32),)
+    if inner_static:
+        # counted loop: iterations after the matching settles are no-ops
+        # (nothing commits twice)
+        carry, _ = jax.lax.scan(
+            lambda c, _: (step(c)[0], None), carry0, None, length=inner_iters
+        )
+        iters = jnp.int32(inner_iters)
+    else:
+
+        def body(c):
+            carry, _, it = c
+            carry, changed = step(carry)
+            return carry, changed, it + jnp.int32(1)
+
+        carry, _, iters = jax.lax.while_loop(
+            lambda c: c[1] & (c[2] < inner_iters),
+            body,
+            (carry0, jnp.bool_(True), jnp.int32(0)),
+        )
+    return carry[2], (iters if idx is not None else jnp.int32(0))
+
+
 class GangScheduler:
     """Fixpoint batch scheduler over one `EncodedCluster`.
 
@@ -185,9 +315,10 @@ class GangScheduler:
         identical to full-width matching whenever every pod commits
         within its k candidates (always true when k == N; `lax.top_k`
         breaks score ties toward lower node indices, matching argmax).
-        It keeps the inner loop at [P, k] instead of the [P, N]
-        select/argmax chain per inner iteration of full-width matching.
-        Default: full width for N <= 512, else 128.
+        Since the lists come sorted, an inner iteration then costs [P]
+        (a per-pod cursor to its first untaken candidate, see
+        `match_candidates`) instead of the [P, N] select/argmax chain of
+        full-width matching. Default: full width for N <= 512, else 128.
 
         `inner_loop` picks the matching iteration's control flow
         independently of the outer loop: None (default) follows `loop`;
@@ -425,6 +556,9 @@ class GangScheduler:
         self._final_state = None
         self._rounds = None
         self._preempt_counts = None
+        # the fused pass's top-k matching iterations (a device scalar);
+        # None after a host-driven (static-loop or record) pass
+        self._match_iters = None
         # record path (results()) — all built/filled lazily so the
         # default fixpoint program and its compile class stay untouched
         self._run_tracked = None
@@ -731,7 +865,6 @@ class GangScheduler:
             default program (`run`) and the bind-round-tracking record
             variant (`run_tracked`) so the two can never drift."""
             in_queue = order != _NO_ORDER
-            C = arrays.pod_claim.shape[1]
             pod_claim = arrays.pod_claim.astype(bool)
             # [P] pods carrying required ANTI-affinity terms — the only
             # cluster-global coupling that needs serialization: positive
@@ -749,87 +882,14 @@ class GangScheduler:
                 else None
             )
 
-            def make_match_step(order_v, pod_claim_v, rel_carrier_v):
-                """Matching iteration over an arbitrary ROW SUBSET of
-                the queue: `order_v`/`pod_claim_v`/`rel_carrier_v` are
-                the [K]-row views (K == P for full rounds; K == the
-                eval window for windowed rounds). Queue positions in
-                `order_v` are global, so the per-node/per-claim
-                earliest-order winner logic is identical either way."""
-
-                def match_step(taken, claim_taken, sel_acc, vals, idx, c_min):
-                    """One matching iteration (shared by both loop
-                    modes): argmax over untaken candidates → per-node
-                    order winner → per-claim order winner → commit.
-                    `vals`/`idx` are the [K, k] top-k candidate
-                    scores/node-indices (idx is None in full-width
-                    mode, where column position == node)."""
-                    node_taken = (
-                        taken[idx] if idx is not None else taken[None, :]
-                    )
-                    m = jnp.where(node_taken, FLOOR, vals)
-                    m = jnp.where((sel_acc >= 0)[:, None], FLOOR, m)
-                    claim_blocked = (
-                        pod_claim_v & claim_taken[None, :]
-                    ).any(axis=1)
-                    m = jnp.where(claim_blocked[:, None], FLOOR, m)
-                    if rel_carrier_v is not None:
-                        # queue-prefix batching: the batched matching
-                        # may only commit pods strictly BEFORE the
-                        # first placeable carrier in queue order —
-                        # carriers (and everything behind them) wait,
-                        # preserving the sequential interleaving at
-                        # carrier boundaries
-                        m = jnp.where((order_v >= c_min)[:, None], FLOOR, m)
-                    col = jnp.argmax(m, axis=1).astype(jnp.int32)
-                    has = (
-                        jnp.take_along_axis(m, col[:, None], axis=1)[:, 0]
-                        > NEG
-                    )
-                    cand = (
-                        jnp.take_along_axis(idx, col[:, None], axis=1)[:, 0]
-                        if idx is not None
-                        else col
-                    )
-                    tgt = jnp.where(has, cand, N)
-                    winner = (
-                        jnp.full((N + 1,), _NO_ORDER, jnp.int32)
-                        .at[tgt]
-                        .min(order_v)
-                    )
-                    commit = has & (winner[jnp.maximum(cand, 0)] == order_v)
-                    claim_order = jnp.where(
-                        commit[:, None] & pod_claim_v,
-                        order_v[:, None],
-                        _NO_ORDER,
-                    )
-                    claim_min = claim_order.min(axis=0)  # [C]
-                    claim_ok = jnp.where(
-                        pod_claim_v,
-                        claim_min[None, :] == order_v[:, None],
-                        True,
-                    ).all(axis=1)
-                    commit = commit & claim_ok
-                    sel_acc = jnp.where(commit, cand, sel_acc)
-                    taken = taken | (
-                        jnp.zeros((N + 1,), bool)
-                        .at[jnp.where(commit, cand, N)]
-                        .set(True)[:N]
-                    )
-                    claim_taken = claim_taken | (
-                        pod_claim_v & commit[:, None]
-                    ).any(axis=0)
-                    return taken, claim_taken, sel_acc, commit.any()
-
-                return match_step
-
             def match(
                 scores, order_v=None, pod_claim_v=None, rel_carrier_v=...,
             ):
                 """One-commit-per-node matching over the round's masked
                 score matrix: argmax → earliest-order winner per node →
                 losers retry their next-best untaken node. No kernel
-                re-evaluation — pure selects over [P, N].
+                re-evaluation — `match_candidates` over the rows'
+                candidate lists. Returns (sel [K], matching iterations).
 
                 ReadWriteOncePod claims are cluster-global, so node
                 serialization alone can't protect them: two claimants
@@ -854,9 +914,11 @@ class GangScheduler:
                 __init__.
 
                 Row-subset form: `order_v`/`pod_claim_v`/`rel_carrier_v`
-                override the full-queue views for windowed rounds (the
-                scores' rows are then the window's pods). Defaults keep
-                the full-round call sites unchanged."""
+                are the [K]-row views for windowed rounds (the scores'
+                rows are then the window's pods; queue positions stay
+                global, so the per-node/per-claim earliest-order winner
+                logic is identical either way). Defaults keep the
+                full-round call sites unchanged."""
                 if order_v is None:
                     order_v = order
                 if pod_claim_v is None:
@@ -864,9 +926,6 @@ class GangScheduler:
                 if rel_carrier_v is ...:
                     rel_carrier_v = rel_carrier
                 K_rows = scores.shape[0]
-                match_step = make_match_step(
-                    order_v, pod_claim_v, rel_carrier_v
-                )
                 if MW < N:
                     vals, idx = jax.lax.top_k(scores, MW)
                     idx = idx.astype(jnp.int32)
@@ -887,51 +946,15 @@ class GangScheduler:
                     prefix_exists = (row_ok & (order_v < c_min)).any()
                     have_carrier = (~prefix_exists) & (c_min != _NO_ORDER)
                 else:
-                    c_min = jnp.int32(_NO_ORDER)
+                    c_min = None
                     have_carrier = None
-                taken0 = jnp.zeros((N,), bool)
-                claims0 = jnp.zeros((C,), bool)
-                sel0 = jnp.full((K_rows,), -1, jnp.int32)
 
                 def run_matching(_):
-                    if inner_static:
-                        # counted loop: iterations after the matching
-                        # settles are no-ops (nothing commits twice)
-                        def m_scan(carry, __):
-                            taken, claim_taken, sel_acc = carry
-                            taken, claim_taken, sel_acc, _ = match_step(
-                                taken, claim_taken, sel_acc, vals, idx, c_min
-                            )
-                            return (taken, claim_taken, sel_acc), None
-
-                        (_, _, sel_acc), _ = jax.lax.scan(
-                            m_scan,
-                            (taken0, claims0, sel0),
-                            None,
-                            length=inner_iters,
-                        )
-                        return sel_acc
-
-                    def m_cond(c):
-                        _, _, _, changed, it = c
-                        return changed & (it < inner_iters)
-
-                    def m_body(c):
-                        taken, claim_taken, sel_acc, _, it = c
-                        taken, claim_taken, sel_acc, changed = match_step(
-                            taken, claim_taken, sel_acc, vals, idx, c_min
-                        )
-                        return (
-                            taken, claim_taken, sel_acc, changed,
-                            it + jnp.int32(1),
-                        )
-
-                    _, _, sel_acc, _, _ = jax.lax.while_loop(
-                        m_cond,
-                        m_body,
-                        (taken0, claims0, sel0, jnp.bool_(True), jnp.int32(0)),
+                    return match_candidates(
+                        vals, idx, order_v, pod_claim_v, c_min,
+                        n_nodes=N, inner_iters=inner_iters,
+                        inner_static=inner_static, neg=FLOOR,
                     )
-                    return sel_acc
 
                 if rel_carrier_v is None:
                     return run_matching(None)
@@ -940,8 +963,9 @@ class GangScheduler:
                 # inner_iters wasted iterations per carrier (under vmap
                 # cond lowers to both-branches select — no worse than
                 # always running it)
-                sel_acc = jax.lax.cond(
-                    have_carrier, lambda _: sel0, run_matching, None
+                no_match = (jnp.full((K_rows,), -1, jnp.int32), jnp.int32(0))
+                sel_acc, iters = jax.lax.cond(
+                    have_carrier, lambda _: no_match, run_matching, None
                 )
                 is_pick = rel_carrier_v & row_ok & (order_v == c_min)
                 col = jnp.argmax(vals, axis=1).astype(jnp.int32)
@@ -951,15 +975,16 @@ class GangScheduler:
                     else col
                 )
                 sel_carrier = jnp.where(is_pick, cand, jnp.int32(-1))
-                return jnp.where(have_carrier, sel_carrier, sel_acc)
+                return jnp.where(have_carrier, sel_carrier, sel_acc), iters
 
             def round_once(state, w_idx=None):
-                """One dense round.
+                """One dense round: (state, progressed, iters), `iters`
+                the round's matching iterations.
 
                 With a BINDING window (WP < P) the caller carries a
                 window offset `w_idx` and gets back
-                (state, w_idx', progressed). The round's dense work —
-                eval, top_k, matching — runs on [WP, N] row-subset
+                (state, w_idx', progressed, iters). The round's dense
+                work — eval, top_k, matching — runs on [WP, N] row-subset
                 tensors ONLY (window `w_idx` of the pending queue, in
                 queue order): per-round cost is bounded by the window
                 regardless of P, and the compiled program carries no
@@ -990,15 +1015,15 @@ class GangScheduler:
                 if W is None or WP is None:
                     scores = eval_all(state, arrays, weights, pending)
                     scores = jnp.where(pending[:, None], scores, FLOOR)
-                    sel = match(scores)
+                    sel, iters = match(scores)
                     commit = sel >= 0
                     state = bind_all(state, arrays, commit, sel, order)
                     committed = commit.any()
                     if W is None:
-                        return state, committed
+                        return state, committed, iters
                     # the window never binds: full rounds with the
                     # windowed carry shape — plain fixpoint signal
-                    return state, jnp.int32(0), committed
+                    return state, jnp.int32(0), committed, iters
 
                 n_pending = pending.sum()
                 perm = jnp.argsort(
@@ -1020,7 +1045,7 @@ class GangScheduler:
                 n_live = jnp.clip(n_pending - start, 0, jnp.int32(WP))
                 scores_w = eval_rows(state, arrays, weights, rows, n_live)
                 scores_w = jnp.where(rows_pending[:, None], scores_w, FLOOR)
-                sel_w = match(
+                sel_w, iters = match(
                     scores_w,
                     order_v=order[rows],
                     pod_claim_v=pod_claim[rows],
@@ -1045,9 +1070,89 @@ class GangScheduler:
                 )
                 done = (~committed) & (k + 1 >= w_max)
                 w_next = jnp.where(committed, jnp.int32(0), w_idx + 1)
-                return state, w_next, ~done
+                return state, w_next, ~done, iters
 
             return round_once
+
+        def run_counted(arrays, state0, order, weights):
+            """(arrays, state0, order, weights) ->
+            (final_state, rounds, matching iterations)."""
+            arrays = unpack(arrays)
+            round_once = make_round_once(arrays, order, weights)
+            zero = jnp.int32(0)
+
+            if static:
+                # counted outer loop too: the whole program is scans, the
+                # same control-flow shape as the sequential engine
+                if W is not None:
+
+                    def rw_scan(carry, _):
+                        state, w_idx = carry
+                        state, w_next, progressed, iters = round_once(
+                            state, w_idx
+                        )
+                        return (state, w_next), (progressed, iters)
+
+                    (state, _), (progressed, iters) = jax.lax.scan(
+                        rw_scan,
+                        (state0, zero),
+                        None,
+                        length=self.static_rounds,
+                    )
+                else:
+
+                    def r_scan(state, _):
+                        state, progressed, iters = round_once(state)
+                        return state, (progressed, iters)
+
+                    state, (progressed, iters) = jax.lax.scan(
+                        r_scan, state0, None, length=self.static_rounds
+                    )
+                return (
+                    state, progressed.sum().astype(jnp.int32), iters.sum()
+                )
+
+            if W is not None:
+                # commit-round budget (see the max_rounds comment above):
+                # w_next == 0 identifies a committing round — a commit
+                # resets the window offset, a no-commit round advances it
+                # past 0
+
+                def w_cond(carry):
+                    _, progressed, _, _, commits, _ = carry
+                    return progressed & (commits < max_rounds)
+
+                def w_body(carry):
+                    state, _, rounds, w_idx, commits, total = carry
+                    state, w_next, progressed, iters = round_once(
+                        state, w_idx
+                    )
+                    commits = commits + (w_next == 0).astype(jnp.int32)
+                    return (
+                        state, progressed, rounds + jnp.int32(1), w_next,
+                        commits, total + iters,
+                    )
+
+                state, _, rounds, _, _, total = jax.lax.while_loop(
+                    w_cond,
+                    w_body,
+                    (state0, jnp.bool_(True), zero, zero, zero, zero),
+                )
+                return state, rounds, total
+
+            def cond(carry):
+                _, progressed, rounds, _ = carry
+                return progressed & (rounds < max_rounds)
+
+            def body(carry):
+                state, _, rounds, total = carry
+                state, progressed, iters = round_once(state)
+                return state, progressed, rounds + jnp.int32(1), total + iters
+
+            state, _, rounds, total = jax.lax.while_loop(
+                cond, body, (state0, jnp.bool_(True), zero, zero)
+            )
+            return state, rounds, total
 
         def run(arrays, state0, order, weights):
             """(arrays, state0, order, weights) -> (final_state, rounds).
@@ -1057,79 +1162,7 @@ class GangScheduler:
             compiled program reusable across retargets and lets sweeps
             vmap over `weights` alone.
             """
-            arrays = unpack(arrays)
-            round_once = make_round_once(arrays, order, weights)
-
-            def cond(carry):
-                _, progressed, rounds = carry
-                return progressed & (rounds < max_rounds)
-
-            if static:
-                # counted outer loop too: the whole program is scans, the
-                # same control-flow shape as the sequential engine
-                if W is not None:
-
-                    def rw_scan(carry, _):
-                        state, w_idx = carry
-                        state, w_next, progressed = round_once(
-                            state, w_idx
-                        )
-                        return (state, w_next), progressed
-
-                    (state, _), progressed = jax.lax.scan(
-                        rw_scan,
-                        (state0, jnp.int32(0)),
-                        None,
-                        length=self.static_rounds,
-                    )
-                    return state, progressed.sum().astype(jnp.int32)
-
-                def r_scan(state, _):
-                    state, progressed = round_once(state)
-                    return state, progressed
-
-                state, progressed = jax.lax.scan(
-                    r_scan, state0, None, length=self.static_rounds
-                )
-                return state, progressed.sum().astype(jnp.int32)
-
-            if W is not None:
-                # commit-round budget (see the max_rounds comment above):
-                # w_next == 0 identifies a committing round — a commit
-                # resets the window offset, a no-commit round advances it
-                # past 0
-
-                def w_cond(carry):
-                    _, progressed, _, _, commits = carry
-                    return progressed & (commits < max_rounds)
-
-                def w_body(carry):
-                    state, _, rounds, w_idx, commits = carry
-                    state, w_next, progressed = round_once(state, w_idx)
-                    commits = commits + (w_next == 0).astype(jnp.int32)
-                    return (
-                        state, progressed, rounds + jnp.int32(1), w_next,
-                        commits,
-                    )
-
-                state, _, rounds, _, _ = jax.lax.while_loop(
-                    w_cond,
-                    w_body,
-                    (
-                        state0, jnp.bool_(True), jnp.int32(0), jnp.int32(0),
-                        jnp.int32(0),
-                    ),
-                )
-                return state, rounds
-
-            def body(carry):
-                state, _, rounds = carry
-                state, progressed = round_once(state)
-                return state, progressed, rounds + jnp.int32(1)
-
-            state, _, rounds = jax.lax.while_loop(
-                cond, body, (state0, jnp.bool_(True), jnp.int32(0))
-            )
+            state, rounds, _ = run_counted(arrays, state0, order, weights)
             return state, rounds
 
         def run_tracked(arrays, state0, order, weights):
@@ -1147,7 +1180,7 @@ class GangScheduler:
 
                     def rw_scan(carry, r):
                         state, br, w_idx = carry
-                        state2, w_next, progressed = round_once(
+                        state2, w_next, progressed, _ = round_once(
                             state, w_idx
                         )
                         newly = (
@@ -1165,7 +1198,7 @@ class GangScheduler:
 
                 def r_scan(carry, r):
                     state, br = carry
-                    state2, progressed = round_once(state)
+                    state2, progressed, _ = round_once(state)
                     newly = (state2.assignment >= 0) & (state.assignment < 0)
                     br = jnp.where(newly, r, br)
                     return (state2, br), progressed
@@ -1186,7 +1219,7 @@ class GangScheduler:
 
                 def tw_body(carry):
                     state, _, rounds, br, w_idx, commits = carry
-                    state2, w_next, progressed = round_once(state, w_idx)
+                    state2, w_next, progressed, _ = round_once(state, w_idx)
                     newly = (state2.assignment >= 0) & (state.assignment < 0)
                     br = jnp.where(newly, rounds, br)
                     commits = commits + (w_next == 0).astype(jnp.int32)
@@ -1211,7 +1244,7 @@ class GangScheduler:
 
             def t_body(carry):
                 state, _, rounds, br = carry
-                state2, progressed = round_once(state)
+                state2, progressed, _ = round_once(state)
                 newly = (state2.assignment >= 0) & (state.assignment < 0)
                 br = jnp.where(newly, rounds, br)
                 return state2, progressed, rounds + jnp.int32(1), br
@@ -1249,9 +1282,11 @@ class GangScheduler:
             a fresh max_rounds commit budget, matching the host driver's
             per-call budget.
 
-            Returns (state, (rounds, preempt)): `preempt` int32[3] sums
-            the phases' (steps, pods bound, pods evicted) — the finish
-            fetches both with the assignment.
+            Returns (state, (rounds, preempt, match_iters)): `preempt`
+            int32[3] sums the phases' (steps, pods bound, pods evicted),
+            `match_iters` the top-k matching iterations of every round
+            (0 at full width) — the finish fetches them with the
+            assignment.
 
             Caveats: under vmap (batch.gang.run) the two `lax.cond`
             guards lower to both-branches-plus-select, so converged
@@ -1264,14 +1299,14 @@ class GangScheduler:
             # widen packed planes ONCE, outside the while_loop — the
             # nested run/preempt_phase unpacks become static no-ops
             arrays = unpack(arrays)
-            state, rounds = run(arrays, state0, order, weights)
+            state, rounds, iters = run_counted(arrays, state0, order, weights)
             no_phase = jnp.zeros(3, jnp.int32)
             if preempt_fn is None:
-                return state, (rounds, no_phase)
+                return state, (rounds, no_phase, iters)
             in_queue = order != _NO_ORDER
 
             def obody(carry):
-                state, rounds, counts, _ = carry
+                state, rounds, counts, iters, _ = carry
                 pending = (
                     (state.assignment < 0) & in_queue & arrays.pod_mask
                 )
@@ -1291,20 +1326,23 @@ class GangScheduler:
                     state,
                 )
                 n_bound = phase[1]
-                state, r2 = jax.lax.cond(
+                state, r2, i2 = jax.lax.cond(
                     n_bound > 0,
-                    lambda s: run(arrays, s, order, weights),
-                    lambda s: (s, jnp.int32(0)),
+                    lambda s: run_counted(arrays, s, order, weights),
+                    lambda s: (s, jnp.int32(0), jnp.int32(0)),
                     state,
                 )
-                return state, rounds + r2, counts + phase, n_bound > 0
+                return (
+                    state, rounds + r2, counts + phase, iters + i2,
+                    n_bound > 0,
+                )
 
-            state, rounds, counts, _ = jax.lax.while_loop(
-                lambda carry: carry[3],
+            state, rounds, counts, iters, _ = jax.lax.while_loop(
+                lambda carry: carry[4],
                 obody,
-                (state, rounds, no_phase, jnp.bool_(True)),
+                (state, rounds, no_phase, iters, jnp.bool_(True)),
             )
-            return state, (rounds, counts)
+            return state, (rounds, counts, iters)
 
         # the fused one-dispatch pass exists only for the dynamic loop:
         # static mode's auto-resume budget is a host decision by design
@@ -1348,6 +1386,7 @@ class GangScheduler:
         self._final_state = None
         self._rounds = None
         self._preempt_counts = None
+        self._match_iters = None
         self._chronology = None
         self._trace = None
         self._recorded_weights = None
@@ -1373,12 +1412,13 @@ class GangScheduler:
             # decode fetch — this is the serving path (async overlap
             # depends on it staying sync-free). `rounds` stays a device
             # scalar; the finish path fetches it with the assignment.
-            state, (rounds, counts) = self._fixpoint(
+            state, (rounds, counts, iters) = self._fixpoint(
                 arrays, self.enc.state0, order, w
             )
             self._final_state = state
             self._rounds = rounds
             self._preempt_counts = counts
+            self._match_iters = iters
             return state, rounds
         if tracked and self._run_tracked is None:
             self._run_tracked = broker_mod.jit(
@@ -1483,6 +1523,7 @@ class GangScheduler:
         self._final_state = state
         self._rounds = rounds
         self._preempt_counts = counts
+        self._match_iters = None
         if tracked:
             self._chronology = chronology
             self._recorded_weights = w
@@ -1745,6 +1786,7 @@ class GangScheduler:
         self._final_state = None
         self._rounds = None
         self._preempt_counts = None
+        self._match_iters = None
         # record state is per-encoding. _run_tracked survives (its
         # shapes are part of the signature just checked); the recorder
         # and its chunk evaluator bake enc-derived statics via their own

@@ -430,7 +430,8 @@ class BatchPlane:
         dt = time.perf_counter() - t0
         for i, it in enumerate(items):
             it.state = jax.tree.map(lambda x, i=i: x[i], state_out)
-            # gang's second output is (rounds scalar, preempt counts);
+            # gang's second output is (rounds, preempt counts, matching
+            # iterations);
             # tree.map slices every shape identically
             it.trace = jax.tree.map(lambda x, i=i: x[i], trace_out)
         # -- accounting -----------------------------------------------------

@@ -875,17 +875,18 @@ class SchedulerService:
         # They are NOT in placements (decode covers queued pods only), so
         # diff the full [P] assignment exactly like the sequential path —
         # upstream preemption deletes victims through the API. One
-        # device_get fetches both sides of the diff, the rounds scalar and
-        # the preempt phases' counts: the host's one block on the device
-        # program; placements decode reads the already-landed `after`
-        # rows (no second sync).
+        # device_get fetches both sides of the diff, the rounds scalar,
+        # the preempt phases' counts and the matching iterations: the
+        # host's one block on the device program; placements decode
+        # reads the already-landed `after` rows (no second sync).
         with telemetry.span("pass.device_wait"):
-            before, after, rounds, preempt = jax.device_get(
+            before, after, rounds, preempt, match_iters = jax.device_get(
                 (
                     enc.state0.assignment,
                     gang._final_state.assignment,
                     gang._rounds,
                     gang._preempt_counts,
+                    gang._match_iters,
                 )
             )
         self._fetched_s = time.perf_counter()  # results on the host
@@ -907,7 +908,10 @@ class SchedulerService:
             # booked here, not at dispatch: the rounds scalar and the
             # phase counts stay on device until this finish-path fetch
             # (async overlap depends on the dispatch staying sync-free)
-            self.metrics.record_gang(fixpoint_rounds=rounds)
+            self.metrics.record_gang(
+                fixpoint_rounds=rounds,
+                match_iters=0 if match_iters is None else int(match_iters),
+            )
             self.metrics.record_preempt(steps=steps, bound=bound, victims=evicted)
             self._delete_victims(enc, before, after)
             if results is not None:
@@ -1483,7 +1487,10 @@ class SchedulerService:
         if out is None:
             self._count_solo_fallback()
             return None
-        gang._final_state, (gang._rounds, gang._preempt_counts) = out
+        (
+            gang._final_state,
+            (gang._rounds, gang._preempt_counts, gang._match_iters),
+        ) = out
         return (enc, gang)
 
     def _seq_finish(self, disp) -> list[PodSchedulingResult]:

@@ -298,9 +298,11 @@ class SchedulingMetrics:
     _solo_fallbacks: int = 0
     # gang-engine counters (engine/gang.py, server/batchplane.py):
     # cumulative commit rounds the device-resident fixpoint used
-    # (rounds/pass = gangFixpointRounds / gang passes) and gang passes
-    # served by a batched dispatch (batch.gang.run) — both per-session
+    # (rounds/pass = gangFixpointRounds / gang passes), the top-k
+    # matching iterations its rounds ran, and gang passes served by a
+    # batched dispatch (batch.gang.run) — all per-session
     _gang_fixpoint_rounds: int = 0
+    _gang_match_iters: int = 0
     _batched_gang_passes: int = 0
     # preemption (engine/gang.py preempt phase; the sequential finish
     # books the same from its records): pods that went through the
@@ -611,16 +613,20 @@ class SchedulingMetrics:
             self._solo_fallbacks += int(solo_fallbacks)
 
     def record_gang(
-        self, *, fixpoint_rounds: int = 0, batched_passes: int = 0
+        self, *, fixpoint_rounds: int = 0, match_iters: int = 0,
+        batched_passes: int = 0,
     ) -> None:
         """Gang-engine accounting: `fixpoint_rounds` commit rounds the
         pass's device-resident fixpoint used (engine/gang.py — booked at
         decode, where the rounds scalar is fetched with the assignment
-        anyway), `batched_passes` gang passes this registry's session
-        had served by a cross-tenant batched dispatch
-        (server/batchplane.py ``batch.gang.run``)."""
+        anyway), `match_iters` the top-k matching iterations those
+        rounds ran (0 at full matching width, and for passes the host
+        drives: static loop or record), `batched_passes` gang passes
+        this registry's session had served by a cross-tenant batched
+        dispatch (server/batchplane.py ``batch.gang.run``)."""
         with self._lock:
             self._gang_fixpoint_rounds += int(fixpoint_rounds)
+            self._gang_match_iters += int(match_iters)
             self._batched_gang_passes += int(batched_passes)
 
     def record_preempt(self, *, steps: int, bound: int, victims: int) -> None:
@@ -727,6 +733,7 @@ class SchedulingMetrics:
                     "batchOccupancySum": self._batch_occupancy_sum,
                     "soloFallbacks": self._solo_fallbacks,
                     "gangFixpointRounds": self._gang_fixpoint_rounds,
+                    "gangMatchIters": self._gang_match_iters,
                     "batchedGangPasses": self._batched_gang_passes,
                     "gangPreemptSteps": self._gang_preempt_steps,
                     "gangPreemptBound": self._gang_preempt_bound,
@@ -793,6 +800,7 @@ class SchedulingMetrics:
             self._batch_occupancy_sum = 0
             self._solo_fallbacks = 0
             self._gang_fixpoint_rounds = 0
+            self._gang_match_iters = 0
             self._batched_gang_passes = 0
             self._gang_preempt_steps = 0
             self._gang_preempt_bound = 0
@@ -817,7 +825,8 @@ class SchedulingMetrics:
         "_bundle_loads", "_bundle_saves", "_bundle_bypasses",
         "_aot_deserialize_s",
         "_batched_passes", "_batch_windows", "_batch_occupancy_sum",
-        "_solo_fallbacks", "_gang_fixpoint_rounds", "_batched_gang_passes",
+        "_solo_fallbacks", "_gang_fixpoint_rounds", "_gang_match_iters",
+        "_batched_gang_passes",
         "_gang_preempt_steps", "_gang_preempt_bound", "_gang_preempt_victims",
     )
 
@@ -1021,6 +1030,11 @@ _PROM_COUNTERS = (
         "kss_gang_fixpoint_rounds_total",
         "Commit rounds used by device-resident gang fixpoint passes.",
         ("phases", "gangFixpointRounds"),
+    ),
+    (
+        "kss_gang_match_iters_total",
+        "Top-k matching iterations run by gang fixpoint rounds.",
+        ("phases", "gangMatchIters"),
     ),
     (
         "kss_batched_gang_passes_total",
